@@ -938,7 +938,11 @@ class ProcessExecutor(ExecutorBackend):
 
     def start(self, runtime) -> None:
         # fork the workers *before* the dispatcher threads exist: forking a
-        # multithreaded process risks inheriting locks held mid-operation
+        # multithreaded process risks inheriting locks held mid-operation.
+        # The LM drivers start the runtime before their first device use,
+        # so no worker inherits a JAX backend, and task bodies are numpy:
+        # only the parent ever holds the accelerator
+        # (tests/test_device_ownership.py)
         self.spawn_workers()
         super().start(runtime)
         self._collector = threading.Thread(target=self._collector_loop,

@@ -1,8 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-Each op auto-selects ``interpret=True`` off-TPU (this container is
-CPU-only; interpret mode executes the kernel body faithfully for
-correctness validation) and exposes the model-layer calling conventions.
+Each op compiles its kernel with Mosaic on a TPU and runs it in interpret
+mode on the CPU (the tests' platform; interpret mode executes the kernel
+body faithfully for correctness validation).  Any other platform is an
+error rather than a silent interpreter run.  The ops expose the
+model-layer calling conventions.
 ``flash_attention_op`` additionally carries a custom_vjp whose backward
 recomputes through the jnp reference — the kernel accelerates the forward
 path while training remains differentiable.
@@ -24,7 +26,11 @@ from .ssd_scan import ssd_scan
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas kernels run compiled on TPU or interpreted "
+                           f"on CPU, not on {platform!r}")
+    return platform == "cpu"
 
 
 # ------------------------------------------------------------ flash attention

@@ -16,12 +16,21 @@ HBM_BW = 819e9                   # bytes/s per chip
 ICI_BW = 50e9                    # bytes/s per link
 
 
+def auto_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the GSPMD rules in
+    ``repro.distributed`` place arrays by ``NamedSharding`` and constraints,
+    which explicit-sharding axes (``make_mesh``'s default) reject."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1, data: int = 1):
     """Small mesh over however many devices exist (tests / CPU smoke)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))

@@ -24,6 +24,7 @@ from ..configs import ARCH_IDS, get_config
 from ..core import api
 from ..distributed.steps import make_decode_step, make_prefill_step
 from ..models.lm import LMConfig, init_params
+from .compile_cache import use_compile_cache
 from .mesh import make_local_mesh
 
 
@@ -63,7 +64,8 @@ def serve_batch(cfg: LMConfig, *, batch: int = 4, prompt_len: int = 32,
         t0 = time.perf_counter()
         dev_prompts = jax.tree.map(jnp.asarray, prompts)
         logits, caches = prefill_j(params, dev_prompts)
-        next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        prefill_logits = logits[:, -1]
+        next_tok = jnp.argmax(prefill_logits, axis=-1).astype(jnp.int32)
         t_prefill = time.perf_counter() - t0
 
         dec_batch = ({"embeds": jnp.zeros((batch, 1, cfg.d_model))}
@@ -96,6 +98,7 @@ def serve_batch(cfg: LMConfig, *, batch: int = 4, prompt_len: int = 32,
         out_tokens = api.wait_on(post_task(generated))
         return {
             "tokens": out_tokens,
+            "prefill_logits": prefill_logits,
             "prefill_s": t_prefill,
             "decode_s": t_decode,
             "decode_tokens_per_s": batch * (gen_len - 1) / max(t_decode, 1e-9),
@@ -113,6 +116,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen-len", type=int, default=16)
     args = ap.parse_args()
+    use_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     out = serve_batch(cfg, batch=args.requests, prompt_len=args.prompt_len,
                       gen_len=args.gen_len)

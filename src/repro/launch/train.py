@@ -34,6 +34,7 @@ from ..distributed.sharding import default_rules, param_pspecs, to_shardings
 from ..distributed.steps import make_train_step
 from ..models.lm import LMConfig, init_params, param_axes
 from ..optim.adamw import adamw, cosine_schedule
+from .compile_cache import use_compile_cache
 from .mesh import make_local_mesh
 
 
@@ -139,6 +140,7 @@ def main() -> None:
     ap.add_argument("--grad-compress", default=None,
                     choices=[None, "int8", "topk"])
     args = ap.parse_args()
+    use_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     out = train_loop(cfg, steps=args.steps, batch=args.batch, seq=args.seq,
                      lr=args.lr, microbatches=args.microbatches,

@@ -4,8 +4,8 @@ KV caches (full or ring-buffer), plus two compute paths:
 * ``dense``   — materialized scores; fine for short sequences.
 * ``chunked`` — flash-style streaming softmax over KV chunks via
   ``lax.scan`` (O(S·chunk) memory).  This is the pure-JAX twin of the Pallas
-  ``flash_attention`` kernel (kernels/flash_attention.py); the CPU dry-run
-  lowers this path, on-TPU runs select the Pallas kernel.
+  ``flash_attention`` kernel (kernels/flash_attention.py).  The model runs
+  this path on every platform: no layer calls the kernel yet.
 
 Cache layout: ``{"k": (B, Sc, K, hd), "v": ..., "pos_map": (Sc,) int32}``.
 ``pos_map[slot]`` holds the absolute position stored in that slot

@@ -185,14 +185,12 @@ def moe_apply_sharded(params, x, *, mesh, top_k: int,
         aux = jax.lax.pmean(aux, tuple(data_axes))
         return out.reshape(Bl, Sl, Dl), aux
 
-    from jax.experimental.shard_map import shard_map
-
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(batch_spec, P(None, None), gate_spec, gate_spec, down_spec),
         out_specs=(batch_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["w_router"], params["w_gate"], params["w_up"], params["w_down"])
     return out, aux
 

@@ -23,7 +23,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .attention import _chunked_attn, _dense_attn
@@ -103,11 +102,11 @@ def tp_attn_sublayer(p_ln, p_attn, x, *, cfg, mesh, window: Optional[int],
         return x_l + out
 
     xspec = P(data_axes, None, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(xspec, {"scale": P(None)},
                   _attn_param_specs(cfg.qk_norm, shard_kv)),
-        out_specs=xspec, check_rep=False,
+        out_specs=xspec, check_vma=False,
     )(x, p_ln, p_attn)
 
 
@@ -137,10 +136,10 @@ def tp_rglru_sublayer(p_ln, p_rec, x, *, cfg, mesh,
         "w_out": P("model", None),
     }
     xspec = P(data_axes, None, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(xspec, {"scale": P(None)}, rspec),
-        out_specs=xspec, check_rep=False,
+        out_specs=xspec, check_vma=False,
     )(x, p_ln, p_rec)
 
 
@@ -160,8 +159,8 @@ def tp_mlp_sublayer(p_ln, p_mlp, x, *, cfg, mesh,
         return x_l + out
 
     xspec = P(data_axes, None, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(xspec, {"scale": P(None)}, _mlp_param_specs(gated)),
-        out_specs=xspec, check_rep=False,
+        out_specs=xspec, check_vma=False,
     )(x, p_ln, p_mlp)
