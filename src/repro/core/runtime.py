@@ -41,7 +41,7 @@ from .futures import Future, ObjectStore, TaskFailedError
 from .memory import budget_from_env
 from .scheduler import Scheduler
 from .telemetry import TelemetryHub, normalize_executor_stats
-from .tracing import TraceEvent, Tracer
+from .tracing import TraceEvent, Tracer, annotate
 
 # per-worker in-flight task budget for pipelined backends (DESIGN.md §14);
 # 1 reproduces the stop-and-wait dispatch of earlier revisions
@@ -630,8 +630,10 @@ class Runtime:
         if ex is None:
             return
         try:
-            result = self.executor.invoke(worker, ex.t.fn, ex.args, ex.kwargs,
-                                          input_keys=ex.input_keys)
+            # the body on this worker's line of a profiler trace
+            with annotate("task." + ex.t.name, task_id=ex.t.task_id):
+                result = self.executor.invoke(worker, ex.t.fn, ex.args, ex.kwargs,
+                                              input_keys=ex.input_keys)
         except BaseException as err:
             self.fail_task(ex, err)
             return

@@ -1,32 +1,42 @@
 """Extrae/Paraver-style execution tracing (paper §3.3.4, Fig. 10).
 
 The tracer records one event per task attempt (worker, node, task name,
-start/end) plus runtime lifecycle events.  From a trace we derive the
-quantities the paper reads off Paraver timelines: per-worker utilization,
-parallel efficiency, serialization share, and an ASCII Gantt rendering for
-quick terminal inspection.  Two file exports: a minimal ``.prv``-like
-format familiar to Paraver users, and the Chrome trace-event JSON
-(``to_chrome_trace``) that opens directly in Perfetto / ``about:tracing``.
+start/end).  From a trace we derive the quantities the paper reads off
+Paraver timelines: per-worker utilization, parallel efficiency, and an
+ASCII Gantt rendering for quick terminal inspection.  Two file exports: a
+minimal ``.prv``-like format familiar to Paraver users, and the Chrome
+trace-event JSON (``to_chrome_trace``) that opens directly in Perfetto /
+``about:tracing``.
 
 :class:`TaskStream` is the live-telemetry counterpart (DESIGN.md §17): a
 *bounded* ring of task-lifecycle events (submit → dispatch → done/fail)
 that the dashboard polls incrementally by sequence number, while the
 tracer above keeps the unbounded post-mortem record.
+
+:class:`span` and :func:`count` are the program's own spans and counters
+(``train.step``, ``data.wait``, ``task.<name>``, ``jit.compiles``...). They
+go into one process-wide ring, :data:`SPANS`, and, once the process has
+imported JAX, also into the profiler's trace as
+``jax.profiler.TraceAnnotation`` spans, on the device trace's clock. This
+module imports no JAX itself.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
+import itertools
 import json
 import os
+import sys
 import threading
 import time
-from dataclasses import dataclass, asdict, field
+from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 
 @dataclass
 class TraceEvent:
-    kind: str            # "task" | "serialize" | "transfer" | "runtime"
+    kind: str            # "task": one task attempt
     name: str
     worker: int
     node: int
@@ -81,12 +91,6 @@ class Tracer:
             return 0.0
         return sum(self.busy_per_worker().values()) / (wall * n_workers)
 
-    def serialization_share(self) -> float:
-        task_t = sum(e.dt for e in self.events("task"))
-        ser_t = sum(e.dt for e in self.events("serialize"))
-        total = task_t + ser_t
-        return ser_t / total if total > 0 else 0.0
-
     def task_duration_stats(self) -> Dict[str, dict]:
         per: Dict[str, List[float]] = {}
         for e in self.events("task"):
@@ -104,9 +108,6 @@ class Tracer:
         return out
 
     # -------------------------------------------------------------- exports
-    def to_json(self) -> str:
-        return json.dumps([asdict(e) for e in self.events()], indent=1)
-
     def to_prv(self) -> str:
         """Tiny Paraver-like export: header + one state record per task.
 
@@ -252,3 +253,161 @@ class TaskStream:
     def __len__(self) -> int:
         with self._lock:
             return len(self._buf)
+
+
+# ------------------------------------------------- program spans and counters
+# A 51 s window of 0.44 s steps is about 1,200 records (some ten spans a
+# step); set-up adds a few hundred compile counts. The ring holds several
+# times that, so a window's spans are never evicted before they are read.
+SPAN_RING = 8192
+SPANS = TaskStream(SPAN_RING)
+
+_local = threading.local()
+_span_ids = itertools.count(1)
+_run_ids = itertools.count(1)
+
+# JAX's compile phases as ``jax.monitoring`` reports them. The backend
+# compile's duration also covers a load from the persistent compilation
+# cache (``/jax/compilation_cache/cache_retrieval_time_sec``), which is
+# therefore not added a second time.
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_PHASES = (_TRACE, _LOWER, _BACKEND)
+
+_annotations = None          # (TraceAnnotation, StepTraceAnnotation) once JAX is in
+_annotations_lock = threading.Lock()
+
+
+def _stack() -> list:
+    stack = getattr(_local, "stack", None)
+    if stack is None:
+        stack = _local.stack = []
+    return stack
+
+
+def _jax_annotations():
+    """JAX's annotation classes once the process has imported JAX, else None.
+    The first time they are found, the compile listeners are registered."""
+    global _annotations
+    if _annotations is None and "jax.monitoring" in sys.modules \
+            and "jax.profiler" in sys.modules:
+        with _annotations_lock:
+            if _annotations is None:
+                prof, mon = sys.modules["jax.profiler"], sys.modules["jax.monitoring"]
+                try:
+                    found = (prof.TraceAnnotation, prof.StepTraceAnnotation)
+                    mon.register_scalar_listener(_compile_phase_begins)
+                    mon.register_event_duration_secs_listener(_compile_phase_ends)
+                except AttributeError:      # JAX is still being imported
+                    return None
+                _annotations = found
+    return _annotations
+
+
+def new_run() -> int:
+    """A fresh run id, for the step spans of one training call."""
+    return next(_run_ids)
+
+
+class span:
+    """A named span of the program, as a context manager::
+
+        with span("train.step", step=k, run=r):
+            with span("train.sync"):      # inherits step k and run r
+                ...
+
+    On exit, by an exception too (which propagates), it appends to
+    :data:`SPANS` one record: ``name``, ``t0``, ``t1`` (``perf_counter``),
+    ``thread``, its ``id`` and ``parent`` (the enclosing span of this
+    thread), ``step`` and ``run`` (given, or the enclosing span's), ``args``
+    and ``error`` (the exception's type name, or None). Once JAX is
+    imported it is also a ``jax.profiler.TraceAnnotation`` (a
+    ``StepTraceAnnotation`` where ``step`` is given), which reaches a trace
+    only while the profiler runs."""
+
+    __slots__ = ("name", "step", "run", "args", "id", "parent", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, step: Optional[int] = None,
+                 run: Optional[int] = None, **args):
+        self.name, self.step, self.run, self.args = name, step, run, args
+
+    def __enter__(self) -> "span":
+        stack = _stack()
+        outer = stack[-1] if stack else None
+        self.id, self.parent = next(_span_ids), outer.id if outer else None
+        is_step = self.step is not None
+        if not is_step and outer is not None:
+            self.step, self.run = outer.step, outer.run
+        ann = _jax_annotations()
+        self._ann = None
+        if ann is not None:
+            meta = dict(self.args)
+            if self.run is not None:
+                meta["run"] = self.run
+            if is_step:
+                self._ann = ann[1](self.name, step_num=self.step, **meta)
+            else:
+                if self.step is not None:
+                    meta["step"] = self.step
+                self._ann = ann[0](self.name, **meta)
+            self._ann.__enter__()
+        stack.append(self)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1 = time.perf_counter()
+        _stack().pop()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        SPANS.append("span", name=self.name, t0=self.t0, t1=self.t1,
+                     thread=threading.get_ident(), id=self.id, parent=self.parent,
+                     step=self.step, run=self.run, args=self.args,
+                     error=exc_type.__name__ if exc_type else None)
+        return False
+
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotate(name: str, **args):
+    """A span on the profiler's clock alone: a ``jax.profiler.TraceAnnotation``
+    once JAX is imported, else a context manager that does nothing. Nothing
+    goes into :data:`SPANS`; for spans too many for the ring that only a
+    trace reads, such as the runtime's task bodies."""
+    ann = _jax_annotations()
+    return _NO_ANNOTATION if ann is None else ann[0](name, **args)
+
+
+def count(name: str, value: float = 1, **args) -> None:
+    """Append a counter record to :data:`SPANS`, tagged like a span opened
+    here would be: ``parent``, ``step`` and ``run`` of this thread's
+    innermost span."""
+    stack = _stack()
+    outer = stack[-1] if stack else None
+    SPANS.append("count", name=name, value=value, t=time.perf_counter(),
+                 thread=threading.get_ident(),
+                 parent=outer.id if outer else None,
+                 step=outer.step if outer else None,
+                 run=outer.run if outer else None, args=args)
+
+
+def _compile_phase_begins(event: str, value, **kw) -> None:
+    if event in _PHASES:
+        _local.compiling = getattr(_local, "compiling", 0) + 1
+
+
+def _compile_phase_ends(event: str, secs: float, **kw) -> None:
+    """``jit.compiles`` counts backend compiles (a cache load included);
+    ``jit.compile_s`` sums the outermost compile phases of this thread, as
+    tracing a function traces the functions it calls, each timed apart."""
+    if event not in _PHASES:
+        return
+    depth = max(getattr(_local, "compiling", 1) - 1, 0)
+    _local.compiling = depth
+    fun = kw.get("fun_name")
+    if event == _BACKEND:
+        count("jit.compiles", fun=fun)
+    if depth == 0:
+        count("jit.compile_s", secs, fun=fun)

@@ -17,6 +17,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from ..core import api
+from ..core.tracing import span
 from ..models.lm import LMConfig
 
 
@@ -82,4 +83,5 @@ class DataPipeline:
         for ahead in range(1, self.depth + 1):
             self._submit(step + ahead)
         fut = self._pending.pop(step)
-        return api.wait_on(fut)
+        with span("data.wait"):         # the consumer waits on the prefetch task
+            return api.wait_on(fut)

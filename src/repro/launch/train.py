@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from ..configs import ARCH_IDS, get_config
 from ..core import api
+from ..core.tracing import new_run, span
 from ..checkpoint.manager import CheckpointManager
 from ..data.pipeline import DataPipeline
 from ..distributed.sharding import default_rules, param_pspecs, to_shardings
@@ -66,10 +67,12 @@ def train_loop(
         opt = adamw(cosine_schedule(lr, warmup, steps), weight_decay=0.01)
         pipeline = DataPipeline(cfg, batch, seq, seed=seed, prefetch_depth=2)
 
-        params = init_params(cfg, jax.random.PRNGKey(seed))
-        opt_state = opt.init(params)
-        pspecs = param_pspecs(param_axes(cfg), params, rules, mesh)
-        p_sh = to_shardings(pspecs, mesh)
+        run = new_run()
+        with span("train.init", run=run):
+            params = init_params(cfg, jax.random.PRNGKey(seed))
+            opt_state = opt.init(params)
+            pspecs = param_pspecs(param_axes(cfg), params, rules, mesh)
+            p_sh = to_shardings(pspecs, mesh)
 
         manager = None
         start_step = 0
@@ -91,32 +94,47 @@ def train_loop(
                          donate_argnums=donate)
 
         losses: List[float] = []
+        step_ends: List[float] = []
         t0 = time.perf_counter()
         batch_np = sample
         for step in range(start_step, steps):
-            dev_batch = jax.tree.map(jnp.asarray, batch_np)
-            params, opt_state, metrics = jitted(params, opt_state, dev_batch)
-            if step + 1 < steps:
-                batch_np = pipeline.get(step + 1)  # prefetched task result
-            loss = float(metrics["loss"])
-            losses.append(loss)
-            if math.isnan(loss):
-                raise FloatingPointError(f"loss NaN at step {step}")
-            if log_every and (step % log_every == 0 or step == steps - 1):
-                print(f"step {step:5d} loss {loss:.4f} "
-                      f"gnorm {float(metrics['grad_norm']):.3f}", flush=True)
-            if manager and ckpt_every and (step + 1) % ckpt_every == 0:
-                manager.save({"params": params, "opt": opt_state}, step + 1,
-                             blocking=False)
-        wall = time.perf_counter() - t0
+            with span("train.step", step=step, run=run) as step_span:
+                with span("train.put"):
+                    dev_batch = jax.tree.map(jnp.asarray, batch_np)
+                with span("train.dispatch"):
+                    params, opt_state, metrics = jitted(params, opt_state, dev_batch)
+                if step + 1 < steps:
+                    with span("train.next_batch"):
+                        batch_np = pipeline.get(step + 1)  # prefetched task result
+                with span("train.sync"):
+                    loss = float(metrics["loss"])
+                losses.append(loss)
+                if math.isnan(loss):
+                    raise FloatingPointError(f"loss NaN at step {step}")
+                if log_every and (step % log_every == 0 or step == steps - 1):
+                    with span("train.log"):
+                        print(f"step {step:5d} loss {loss:.4f} "
+                              f"gnorm {float(metrics['grad_norm']):.3f}", flush=True)
+                if manager and ckpt_every and (step + 1) % ckpt_every == 0:
+                    with span("train.ckpt"):
+                        manager.save({"params": params, "opt": opt_state}, step + 1,
+                                     blocking=False)
+            step_ends.append(step_span.t1)
+        # the rate after the first step, which compiles; the whole call where
+        # there is only one step
+        if len(step_ends) > 1:
+            tokens_per_s = ((len(step_ends) - 1) * batch * seq
+                            / (step_ends[-1] - step_ends[0]))
+        else:
+            tokens_per_s = len(step_ends) * batch * seq / max(
+                time.perf_counter() - t0, 1e-9)
         if manager:
             manager.wait()
             manager.save({"params": params, "opt": opt_state}, steps)
         api.barrier()
-        tokens = (steps - start_step) * batch * seq
         return {"losses": losses, "steps_done": steps - start_step,
                 "restored_from": restored_from,
-                "tokens_per_s": tokens / max(wall, 1e-9),
+                "tokens_per_s": tokens_per_s,
                 "runtime_stats": api.current_runtime().stats()}
     finally:
         if manage_runtime:

@@ -68,6 +68,7 @@ def _mask(q_pos, kv_pos, window: Optional[int]):
     return m
 
 
+@jax.named_scope("scores")
 def _dense_attn(q, k, v, q_pos, kv_pos, window):
     """q: (B,Sq,K,G,hd); k,v: (B,Skv,K,hd) -> (B,Sq,K,G,hd) fp32."""
     scale = 1.0 / math.sqrt(q.shape[-1])
@@ -80,6 +81,7 @@ def _dense_attn(q, k, v, q_pos, kv_pos, window):
     return o
 
 
+@jax.named_scope("scores")
 def _chunked_attn(q, k, v, q_pos, kv_pos, window, chunk: int = 1024,
                   unroll: bool = False, scores_dtype=jnp.float32):
     """Streaming (online-softmax) attention over KV chunks.
@@ -158,6 +160,7 @@ def _build_cache(k, v, positions, cache_len: int, dtype):
     return {"k": ck, "v": cv, "pos_map": pos_map}
 
 
+@jax.named_scope("attention")
 def attn_forward(
     params,
     x,

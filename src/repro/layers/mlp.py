@@ -30,6 +30,7 @@ def mlp_axes(gated: bool = True):
     return ax
 
 
+@jax.named_scope("mlp")
 def mlp_forward(params, x):
     if "w_gate" in params:
         h = jax.nn.silu(x @ params["w_gate"]) * (x @ params["w_up"])

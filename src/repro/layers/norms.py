@@ -1,6 +1,7 @@
 """RMSNorm (fused Pallas twin in kernels/rmsnorm.py)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -12,6 +13,7 @@ def rmsnorm_axes():
     return {"scale": ("embed",)}
 
 
+@jax.named_scope("rmsnorm")
 def rmsnorm(params, x, eps: float = 1e-6):
     dtype = x.dtype
     xf = x.astype(jnp.float32)
@@ -20,6 +22,7 @@ def rmsnorm(params, x, eps: float = 1e-6):
     return (y * params["scale"].astype(jnp.float32)).astype(dtype)
 
 
+@jax.named_scope("rmsnorm")
 def rmsnorm_plain(x, eps: float = 1e-6):
     """Scale-free RMS normalization (qk-norm without learned gain)."""
     xf = x.astype(jnp.float32)

@@ -435,7 +435,8 @@ def forward(cfg: LMConfig, params, batch, *, caches=None, pos_offset=0,
         x = x[:, -1:]
     x = rmsnorm(params["final_norm"], x)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
+    with jax.named_scope("logits"):
+        logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
     return logits, (new_caches or None), aux_total
 
 
@@ -446,10 +447,11 @@ def loss_fn(cfg: LMConfig, params, batch, *, mesh=None, aux_weight: float = 0.01
     logits, _, aux = forward(cfg, params, batch, mesh=mesh, remat=remat)
     targets = batch["targets"]
     mask = batch["loss_mask"].astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    nll = (logz - gold) * mask
-    denom = jnp.maximum(jnp.sum(mask), 1.0)
-    loss = jnp.sum(nll) / denom
+    with jax.named_scope("cross_entropy"):
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        nll = (logz - gold) * mask
+        denom = jnp.maximum(jnp.sum(mask), 1.0)
+        loss = jnp.sum(nll) / denom
     total = loss + aux_weight * aux
     return total, {"loss": loss, "aux": aux, "tokens": jnp.sum(mask)}

@@ -56,6 +56,7 @@ def adamw(lr: Callable | float, b1: float = 0.9, b2: float = 0.95,
             nu=jax.tree.map(zeros, params),
         )
 
+    @jax.named_scope("adamw")
     def update(grads, state: AdamWState, params):
         gnorm = jnp.zeros((), jnp.float32)
         if max_grad_norm is not None:
