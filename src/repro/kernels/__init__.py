@@ -1,7 +1,12 @@
 """Pallas TPU kernels for the compute hot-spots (DESIGN.md §7), each with a
 pure-jnp oracle in ``ref.py`` and a jit'd wrapper in ``ops.py``:
 
-* ``flash_attention`` — GQA causal/windowed flash attention
+* ``ops.flash_attention_op`` — causal GQA attention through JAX's Pallas
+  splash kernels (fused forward, fused dq and dk/dv backward); the one
+  kernel on the model's path, selected by ``layers.attention.attn_path``
+  for training and prefill on a single TPU
+* ``flash_attention`` — the repo's own forward-only GQA causal/windowed
+  flash kernel; reached only by its tests
 * ``ssd_scan``        — Mamba-2 SSD chunked scan
 * ``rglru_scan``      — RG-LRU linear recurrence
 * ``knn_topk``        — fused distance + running top-k (paper's KNN_frag)

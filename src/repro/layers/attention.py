@@ -1,11 +1,19 @@
 """Grouped-query attention with RoPE, optional qk-norm, sliding window, and
-KV caches (full or ring-buffer), plus two compute paths:
+KV caches (full or ring-buffer), over three compute paths that
+:func:`attn_path` picks from the platform and the shapes:
 
-* ``dense``   — materialized scores; fine for short sequences.
+* ``flash``   — the fused Pallas splash kernels, forward and backward
+  (``kernels.ops.flash_attention_op``): scores and probabilities stay in
+  VMEM.  Taken by causal self-attention without a cache or a window
+  (training and prefill) on a single TPU, with bfloat16 activations and
+  both the sequence and the head width multiples of 128.
+* ``dense``   — materialized float32 scores; everything else up to 2048 keys
+  (decode, sliding windows, hd 64, float32 models, the CPU).
 * ``chunked`` — flash-style streaming softmax over KV chunks via
-  ``lax.scan`` (O(S·chunk) memory).  This is the pure-JAX twin of the Pallas
-  ``flash_attention`` kernel (kernels/flash_attention.py).  The model runs
-  this path on every platform: no layer calls the kernel yet.
+  ``lax.scan`` (O(S·chunk) memory); everything else past 2048 keys.
+
+``impl="dense"`` and ``impl="chunked"`` force those jnp paths.  Each traced
+call counts its path as ``attn.impl`` in ``core.tracing.SPANS``.
 
 Cache layout: ``{"k": (B, Sc, K, hd), "v": ..., "pos_map": (Sc,) int32}``.
 ``pos_map[slot]`` holds the absolute position stored in that slot
@@ -22,6 +30,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..core import tracing
+from ..kernels.ops import flash_attention_op, flash_block_sizes
 from .norms import rmsnorm
 from .rope import apply_rope, rope_angles
 
@@ -66,6 +76,31 @@ def _mask(q_pos, kv_pos, window: Optional[int]):
     if window is not None:
         m &= (q_pos[:, None] - kv_pos[None, :]) < window
     return m
+
+
+def attn_path(impl: str, *, seq_len: int, kv_len: int, head_dim: int, dtype,
+              window: Optional[int], cached: bool, devices: int = 1,
+              platform: Optional[str] = None) -> str:
+    """The path ``attn_forward`` takes: ``"flash"``, ``"dense"`` or
+    ``"chunked"`` (module docstring).  ``platform`` defaults to JAX's
+    default backend.  The fused kernels need one device: Mosaic kernels are
+    not partitioned by GSPMD, so a mesh of several keeps the jnp paths."""
+    if impl in ("dense", "chunked"):
+        return impl
+    platform = platform or jax.default_backend()
+    if (platform == "tpu" and devices == 1 and not cached and window is None
+            and jnp.dtype(dtype) == jnp.bfloat16
+            and flash_block_sizes(seq_len, head_dim) is not None):
+        return "flash"
+    return "chunked" if kv_len > 2048 else "dense"
+
+
+@jax.named_scope("scores")
+def _flash_attn(q, k, v):
+    """q: (B,S,K,G,hd); k,v: (B,S,K,hd) -> (B,S,K,G,hd) bf16, causal."""
+    o = flash_attention_op(q.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3),
+                           v.transpose(0, 2, 1, 3))
+    return o.transpose(0, 3, 1, 2, 4)
 
 
 @jax.named_scope("scores")
@@ -179,8 +214,10 @@ def attn_forward(
     chunk: int = 1024,
     unroll: bool = False,
     scores_dtype=jnp.float32,
+    mesh=None,
 ) -> Tuple[jnp.ndarray, Optional[dict]]:
-    """Returns (output, new_cache).
+    """Returns (output, new_cache).  ``mesh``: the mesh the step runs on,
+    for :func:`attn_path` (None: one device).
 
     * training: ``cache=None, make_cache_len=None`` — block-local attention.
     * prefill:  ``make_cache_len=Sc`` — same attention, plus a cache built
@@ -216,8 +253,13 @@ def attn_forward(
             new_cache = _build_cache(k, v, positions, make_cache_len, cache_dtype)
 
     qg = q.reshape(B, S, K, G, head_dim)
-    use_chunked = impl == "chunked" or (impl == "auto" and k_all.shape[1] > 2048)
-    if use_chunked:
+    path = attn_path(impl, seq_len=S, kv_len=k_all.shape[1], head_dim=head_dim,
+                     dtype=q.dtype, window=window, cached=cache is not None,
+                     devices=1 if mesh is None else mesh.size)
+    tracing.count("attn.impl", 1, impl=path)
+    if path == "flash":
+        o = _flash_attn(qg, k_all, v_all)
+    elif path == "chunked":
         o = _chunked_attn(qg, k_all, v_all, positions, kv_pos, window,
                           chunk=chunk, unroll=unroll, scores_dtype=scores_dtype)
     else:

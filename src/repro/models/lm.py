@@ -292,7 +292,7 @@ def _apply_block(cfg: LMConfig, p, x, btype: str, *, cache, pos_offset,
                 cache_dtype=cfg.cache_dtype, impl=cfg.attn_impl,
                 chunk=cfg.attn_chunk, unroll=cfg.unroll_scans,
                 scores_dtype=jnp.bfloat16 if cfg.attn_scores_bf16
-                else jnp.float32)
+                else jnp.float32, mesh=mesh)
             x = x + a
         h = rmsnorm(p["ln2"], x)
         if btype == "moe":

@@ -1,5 +1,6 @@
-"""Compile the main-path kernels and the qwen3-0.6b decode step for a
-described TPU v5e, without a chip.
+"""Compile the main-path kernels (the fused attention forward and backward
+among them) and the qwen3-0.6b decode step for a described TPU v5e,
+without a chip.
 
 Interpret mode (the CPU tests) cannot see what Mosaic refuses: unsupported
 primitives, tile-misaligned slices, scalar stores to VMEM, VMEM overflow.
@@ -20,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.distributed.steps import make_decode_step, make_prefill_step
+from repro.kernels import ops
 from repro.kernels.kmeans_assign import kmeans_assign
 from repro.kernels.knn_topk import knn_topk
 from repro.kernels.rmsnorm import rmsnorm
@@ -88,6 +90,25 @@ def test_rmsnorm_compiles_for_v5e(one_chip):
                                       sharding=one_chip),
                  jax.ShapeDtypeStruct((1024,), jnp.bfloat16, sharding=one_chip))
     assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("B,K,G,S", [(4, 8, 2, 2048), (1, 8, 2, 4096)])
+def test_flash_attention_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch,
+                                                  B, K, G, S):
+    """The training path's attention at the benchmark's shape (and a longer
+    sequence): the forward and the fused backward are Mosaic kernels."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)   # the CPU's branch
+    hd = 128
+    q = jax.ShapeDtypeStruct((B, K, G, S, hd), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, K, S, hd), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(ops.flash_attention_op(q, k, v).astype(jnp.float32))
+
+    c = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    text = c.as_text()
+    assert "splash_mqa_fwd_residuals" in text and "splash_mqa_dkv" in text
+    assert "splash_mqa_dq" not in text          # dq comes from the dkv kernel
 
 
 def test_qwen3_decode_step_compiles_for_v5e_at_full_width(topo):
