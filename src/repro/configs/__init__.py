@@ -1,6 +1,6 @@
-"""Architecture registry: the 10 assigned architectures (``--arch <id>``),
-each with its exact published configuration (FULL) and a smoke-test
-REDUCED variant, plus the shape sets and ShapeDtypeStruct input specs."""
+"""Architecture registry: the 11 architectures (``--arch <id>``), each with
+its exact published configuration (FULL) and a smoke-test REDUCED variant,
+plus the shape sets and ShapeDtypeStruct input specs."""
 from __future__ import annotations
 
 from importlib import import_module
@@ -28,6 +28,7 @@ _MODULES: Dict[str, str] = {
     "internvl2-26b": "internvl2_26b",
     "musicgen-medium": "musicgen_medium",
     "recurrentgemma-9b": "recurrentgemma_9b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 ARCH_IDS: List[str] = list(_MODULES)

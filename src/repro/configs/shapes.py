@@ -50,9 +50,13 @@ SMOKE_SHAPES: Dict[str, ShapeSpec] = {
 
 
 def shape_applicable(cfg: LMConfig, shape_name: str) -> bool:
-    """Assignment rule: ``long_500k`` only for sub-quadratic archs."""
+    """Assignment rule: ``long_500k`` only for sub-quadratic archs; prefill
+    and decode only where every layer keeps a KV cache (not latent
+    attention, not leading dense layers)."""
     if shape_name == "long_500k":
         return cfg.sub_quadratic
+    if SHAPES[shape_name].kind in ("prefill", "decode"):
+        return not (cfg.kv_lora_rank or cfg.first_k_dense)
     return True
 
 

@@ -60,6 +60,7 @@ def default_rules(mesh: Mesh) -> ShardingRules:
         "heads": "model",
         "kv_heads": "model",
         "head_dim": None,
+        "kv_lora": None,        # latent attention's compressed KV width
         "mlp": "model",
         "expert": "model",      # EP
         "expert_mlp": fsdp,     # FSDP storage; gathered inside MoE shard_map

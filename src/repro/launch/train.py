@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from ..configs import ARCH_IDS, get_config
 from ..core import api
-from ..core.tracing import new_run, span
+from ..core.tracing import count, new_run, span
 from ..checkpoint.manager import CheckpointManager
 from ..data.pipeline import DataPipeline
 from ..distributed.sharding import default_rules, param_pspecs, to_shardings
@@ -108,6 +108,8 @@ def train_loop(
                         batch_np = pipeline.get(step + 1)  # prefetched task result
                 with span("train.sync"):
                     loss = float(metrics["loss"])
+                    if "moe_rows" in metrics:     # computed: the loss has synced
+                        _count_moe_rows(metrics["moe_rows"])
                 losses.append(loss)
                 if math.isnan(loss):
                     raise FloatingPointError(f"loss NaN at step {step}")
@@ -139,6 +141,13 @@ def train_loop(
     finally:
         if manage_runtime:
             api.runtime_stop()
+
+
+def _count_moe_rows(moe_rows) -> None:
+    """One ``moe.rows`` count per MoE layer of this step: the rows its held
+    experts computed, with the largest held expert's rows beside them."""
+    for layer, (here, largest) in enumerate(jax.device_get(moe_rows).tolist()):
+        count("moe.rows", here, layer=layer, largest=largest)
 
 
 def main() -> None:

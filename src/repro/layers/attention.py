@@ -5,8 +5,9 @@ KV caches (full or ring-buffer), over three compute paths that
 * ``flash``   — the fused Pallas splash kernels, forward and backward
   (``kernels.ops.flash_attention_op``): scores and probabilities stay in
   VMEM.  Taken by causal self-attention without a cache or a window
-  (training and prefill) on a single TPU, with bfloat16 activations and
-  both the sequence and the head width multiples of 128.
+  (training and prefill) on a single TPU, with bfloat16 activations, the
+  sequence a multiple of 128 and head widths the kernels' tiles hold
+  (``kernels.ops.flash_block_sizes``: 128, 192 or 256, not 64).
 * ``dense``   — materialized float32 scores; everything else up to 2048 keys
   (decode, sliding windows, hd 64, float32 models, the CPU).
 * ``chunked`` — flash-style streaming softmax over KV chunks via
@@ -14,6 +15,9 @@ KV caches (full or ring-buffer), over three compute paths that
 
 ``impl="dense"`` and ``impl="chunked"`` force those jnp paths.  Each traced
 call counts its path as ``attn.impl`` in ``core.tracing.SPANS``.
+:func:`attend` is the part after the projections, shared with latent
+attention (``layers.mla``), whose value width differs from its query/key
+width and whose softmax scale is given.
 
 Cache layout: ``{"k": (B, Sc, K, hd), "v": ..., "pos_map": (Sc,) int32}``.
 ``pos_map[slot]`` holds the absolute position stored in that slot
@@ -80,7 +84,8 @@ def _mask(q_pos, kv_pos, window: Optional[int]):
 
 def attn_path(impl: str, *, seq_len: int, kv_len: int, head_dim: int, dtype,
               window: Optional[int], cached: bool, devices: int = 1,
-              platform: Optional[str] = None) -> str:
+              platform: Optional[str] = None,
+              head_dim_v: Optional[int] = None) -> str:
     """The path ``attn_forward`` takes: ``"flash"``, ``"dense"`` or
     ``"chunked"`` (module docstring).  ``platform`` defaults to JAX's
     default backend.  The fused kernels need one device: Mosaic kernels are
@@ -90,23 +95,25 @@ def attn_path(impl: str, *, seq_len: int, kv_len: int, head_dim: int, dtype,
     platform = platform or jax.default_backend()
     if (platform == "tpu" and devices == 1 and not cached and window is None
             and jnp.dtype(dtype) == jnp.bfloat16
-            and flash_block_sizes(seq_len, head_dim) is not None):
+            and flash_block_sizes(seq_len, head_dim, head_dim_v) is not None):
         return "flash"
     return "chunked" if kv_len > 2048 else "dense"
 
 
 @jax.named_scope("scores")
-def _flash_attn(q, k, v):
-    """q: (B,S,K,G,hd); k,v: (B,S,K,hd) -> (B,S,K,G,hd) bf16, causal."""
+def _flash_attn(q, k, v, scale: Optional[float] = None):
+    """q: (B,S,K,G,hd); k: (B,S,K,hd); v: (B,S,K,hd_v) -> (B,S,K,G,hd_v)
+    bf16, causal."""
     o = flash_attention_op(q.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3),
-                           v.transpose(0, 2, 1, 3))
+                           v.transpose(0, 2, 1, 3), scale=scale)
     return o.transpose(0, 3, 1, 2, 4)
 
 
 @jax.named_scope("scores")
-def _dense_attn(q, k, v, q_pos, kv_pos, window):
-    """q: (B,Sq,K,G,hd); k,v: (B,Skv,K,hd) -> (B,Sq,K,G,hd) fp32."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+def _dense_attn(q, k, v, q_pos, kv_pos, window, scale: Optional[float] = None):
+    """q: (B,Sq,K,G,hd); k: (B,Skv,K,hd); v: (B,Skv,K,hd_v) ->
+    (B,Sq,K,G,hd_v) fp32."""
+    scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
     s = jnp.einsum("bqkgh,bskh->bkgqs", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     mask = _mask(q_pos, kv_pos, window)
@@ -118,7 +125,8 @@ def _dense_attn(q, k, v, q_pos, kv_pos, window):
 
 @jax.named_scope("scores")
 def _chunked_attn(q, k, v, q_pos, kv_pos, window, chunk: int = 1024,
-                  unroll: bool = False, scores_dtype=jnp.float32):
+                  unroll: bool = False, scores_dtype=jnp.float32,
+                  scale: Optional[float] = None):
     """Streaming (online-softmax) attention over KV chunks.
 
     ``scores_dtype=bfloat16`` stores the (B,K,G,Sq,chunk) score/probability
@@ -133,10 +141,11 @@ def _chunked_attn(q, k, v, q_pos, kv_pos, window, chunk: int = 1024,
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         kv_pos = jnp.pad(kv_pos, (0, pad), constant_values=INVALID_POS)
+    hd_v = v.shape[-1]
     kc = k.reshape(B, n_chunks, chunk, K, hd).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(B, n_chunks, chunk, K, hd).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(B, n_chunks, chunk, K, hd_v).transpose(1, 0, 2, 3, 4)
     pc = kv_pos.reshape(n_chunks, chunk)
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     sd = scores_dtype
     qf = q.astype(sd)
 
@@ -157,7 +166,7 @@ def _chunked_attn(q, k, v, q_pos, kv_pos, window, chunk: int = 1024,
 
     m0 = jnp.full((B, K, G, Sq), NEG_INF, dtype=jnp.float32)
     l0 = jnp.zeros((B, K, G, Sq), dtype=jnp.float32)
-    a0 = jnp.zeros((B, K, G, Sq, hd), dtype=jnp.float32)
+    a0 = jnp.zeros((B, K, G, Sq, hd_v), dtype=jnp.float32)
     (m, lse, acc), _ = jax.lax.scan(step, (m0, l0, a0), (kc, vc, pc),
                                   unroll=n_chunks if unroll else 1)
     o = acc / jnp.maximum(lse, 1e-30)[..., None]
@@ -252,17 +261,29 @@ def attn_forward(
         if make_cache_len is not None:
             new_cache = _build_cache(k, v, positions, make_cache_len, cache_dtype)
 
-    qg = q.reshape(B, S, K, G, head_dim)
-    path = attn_path(impl, seq_len=S, kv_len=k_all.shape[1], head_dim=head_dim,
-                     dtype=q.dtype, window=window, cached=cache is not None,
+    o = attend(q.reshape(B, S, K, G, head_dim), k_all, v_all, positions, kv_pos,
+               window=window, cached=cache is not None, impl=impl, chunk=chunk,
+               unroll=unroll, scores_dtype=scores_dtype, mesh=mesh)
+    o = o.astype(x.dtype).reshape(B, S, n_heads * head_dim)
+    return o @ params["wo"], new_cache
+
+
+def attend(q, k, v, positions, kv_pos, *, window: Optional[int] = None,
+           cached: bool = False, scale: Optional[float] = None,
+           impl: str = "auto", chunk: int = 1024, unroll: bool = False,
+           scores_dtype=jnp.float32, mesh=None):
+    """Attention over projected heads, on the path :func:`attn_path` picks
+    (counted as ``attn.impl``).  q: (B,S,K,G,hd); k: (B,Skv,K,hd); v:
+    (B,Skv,K,hd_v) -> (B,S,K,G,hd_v).  ``scale``: 1/sqrt(hd) where not
+    given."""
+    path = attn_path(impl, seq_len=q.shape[1], kv_len=k.shape[1],
+                     head_dim=q.shape[-1], head_dim_v=v.shape[-1], dtype=q.dtype,
+                     window=window, cached=cached,
                      devices=1 if mesh is None else mesh.size)
     tracing.count("attn.impl", 1, impl=path)
     if path == "flash":
-        o = _flash_attn(qg, k_all, v_all)
-    elif path == "chunked":
-        o = _chunked_attn(qg, k_all, v_all, positions, kv_pos, window,
-                          chunk=chunk, unroll=unroll, scores_dtype=scores_dtype)
-    else:
-        o = _dense_attn(qg, k_all, v_all, positions, kv_pos, window)
-    o = o.astype(x.dtype).reshape(B, S, n_heads * head_dim)
-    return o @ params["wo"], new_cache
+        return _flash_attn(q, k, v, scale=scale)
+    if path == "chunked":
+        return _chunked_attn(q, k, v, positions, kv_pos, window, chunk=chunk,
+                             unroll=unroll, scores_dtype=scores_dtype, scale=scale)
+    return _dense_attn(q, k, v, positions, kv_pos, window, scale=scale)

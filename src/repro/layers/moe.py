@@ -19,9 +19,22 @@ computation runs inside ``shard_map``:
 Tokens overflowing an expert's capacity ``C = ceil(N·k·cf / E)`` are dropped
 (pass through the residual only) — standard capacity-based semantics.
 
-``moe_apply_local`` is the same algorithm without collectives (model-axis
-size 1); it doubles as the test oracle target and the single-device path.
-``moe_reference`` is the exact dense loop used to validate the dispatch.
+``moe_apply_local`` is the single-device path, and drops nothing: the
+N·k assignments are sorted by expert, the held experts' rows run through
+grouped matmuls (``kernels.ops.grouped_matmul``) and the weighted rows are
+added back in token order, so every assignment is computed under any
+imbalance.  A layer that holds the experts ``expert_offset`` to
+``expert_offset + E_l`` of the router's ``E`` routes over all ``E`` and
+returns the part of the result its own experts give (expert parallelism's
+share, without the exchange).
+
+The router is ``softmax`` (top-k of the probabilities, with the Switch
+load-balance term) or ``sigmoid`` (DeepSeek-V3's ``noaux_tc``: top-k of
+the scores plus a correction bias that is state outside the gradient, the
+weights the scores without it; no auxiliary term), optionally renormalised
+to sum to one, then multiplied by ``routed_scale``.
+
+``moe_reference`` is the exact dense loop used to validate both paths.
 """
 from __future__ import annotations
 
@@ -32,52 +45,75 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..kernels.ops import grouped_matmul
+
 
 def init_moe(key, d_model: int, d_ff_expert: int, n_experts: int,
-             dtype=jnp.float32):
+             dtype=jnp.float32, n_held: Optional[int] = None,
+             scoring: str = "softmax"):
+    """Router over ``n_experts``; expert weights for the first ``n_held``
+    of them (all where not given), drawn as for all and sliced, so an
+    expert's weights do not depend on how many are held. The sigmoid
+    router adds its correction bias, zero."""
     ks = jax.random.split(key, 4)
     s_in = 1.0 / math.sqrt(d_model)
     s_out = 1.0 / math.sqrt(d_ff_expert)
-    return {
+    held = n_experts if n_held is None else n_held
+    p = {
         "w_router": (jax.random.normal(ks[0], (d_model, n_experts)) * s_in
                      ).astype(jnp.float32),  # router kept fp32 (standard)
-        "w_gate": (jax.random.normal(ks[1], (n_experts, d_model, d_ff_expert)) * s_in
-                   ).astype(dtype),
-        "w_up": (jax.random.normal(ks[2], (n_experts, d_model, d_ff_expert)) * s_in
-                 ).astype(dtype),
-        "w_down": (jax.random.normal(ks[3], (n_experts, d_ff_expert, d_model)) * s_out
-                   ).astype(dtype),
+        "w_gate": (jax.random.normal(ks[1], (n_experts, d_model, d_ff_expert))[:held]
+                   * s_in).astype(dtype),
+        "w_up": (jax.random.normal(ks[2], (n_experts, d_model, d_ff_expert))[:held]
+                 * s_in).astype(dtype),
+        "w_down": (jax.random.normal(ks[3], (n_experts, d_ff_expert, d_model))[:held]
+                   * s_out).astype(dtype),
     }
+    if scoring == "sigmoid":
+        p["e_score_correction_bias"] = jnp.zeros((n_experts,), jnp.float32)
+    return p
 
 
-def moe_axes():
-    return {
+def moe_axes(scoring: str = "softmax"):
+    ax = {
         "w_router": ("embed", "expert_router"),
         "w_gate": ("expert", "embed", "expert_mlp"),
         "w_up": ("expert", "embed", "expert_mlp"),
         "w_down": ("expert", "expert_mlp", "embed"),
     }
+    if scoring == "sigmoid":
+        ax["e_score_correction_bias"] = ("expert_router",)
+    return ax
 
 
-def _route(xf, w_router, top_k: int, renormalize: bool = True):
+def _route(xf, params, top_k: int, scoring: str = "softmax",
+           renormalize: bool = True, routed_scale: float = 1.0):
     """xf: (N, D) -> (weights (N,k) fp32, ids (N,k) int32, aux_loss scalar)."""
-    logits = xf.astype(jnp.float32) @ w_router
-    probs = jax.nn.softmax(logits, axis=-1)
-    topw, topi = jax.lax.top_k(probs, top_k)
+    logits = jnp.dot(xf.astype(jnp.float32), params["w_router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    E = logits.shape[1]
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        bias = jax.lax.stop_gradient(params["e_score_correction_bias"])
+        _, topi = jax.lax.top_k(scores + bias, top_k)
+        topw = jnp.take_along_axis(scores, topi, axis=-1)
+        aux = jnp.zeros((), jnp.float32)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        topw, topi = jax.lax.top_k(probs, top_k)
+        # Switch-style load-balance aux: E * mean_e(frac_tokens_e * mean_prob_e)
+        me = jnp.mean(probs, axis=0)
+        ce = jnp.mean(jax.nn.one_hot(topi[:, 0], E, dtype=jnp.float32), axis=0)
+        aux = E * jnp.sum(me * ce)
     if renormalize:
         topw = topw / jnp.maximum(jnp.sum(topw, axis=-1, keepdims=True), 1e-9)
-    # Switch-style load-balance aux: E * mean_e(frac_tokens_e * mean_prob_e)
-    E = w_router.shape[1]
-    me = jnp.mean(probs, axis=0)
-    onehot = jax.nn.one_hot(topi[:, 0], E, dtype=jnp.float32)
-    ce = jnp.mean(onehot, axis=0)
-    aux = E * jnp.sum(me * ce)
-    return topw, topi.astype(jnp.int32), aux
+    return topw * routed_scale, topi.astype(jnp.int32), aux
 
 
 def _dispatch_compute(xf, topw, topi, w_gate, w_up, w_down,
                       expert_offset, n_experts_total: int, capacity: int):
-    """Sort-based capacity dispatch for the local expert slice.
+    """Sort-based capacity dispatch for the local expert slice (the sharded
+    path).
 
     xf: (N, D); topw/topi: (N, k); w_*: (E_l, D, F)/(E_l, F, D).
     Returns (N, D) contribution of the local experts (zeros elsewhere).
@@ -125,28 +161,121 @@ def moe_capacity(n_tokens: int, top_k: int, n_experts: int,
     return max(min_capacity, int(math.ceil(n_tokens * top_k * capacity_factor / n_experts)))
 
 
-def moe_apply_local(params, x, *, top_k: int, capacity_factor: float = 1.25,
-                    min_capacity: int = 4, renormalize: bool = True,
-                    expert_offset=0, n_experts_total: Optional[int] = None,
-                    capacity: Optional[int] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Single-shard routed-MoE: x (B, S, D) -> (out, aux_loss)."""
+def _sum_k(rows, weights=None):
+    """sum_j weights[:, j] * rows[:, j] over the k of (N, k, D) rows, in
+    float32, one slice at a time: no float32 copy of all N·k rows."""
+    out = None
+    for j in range(rows.shape[1]):
+        r = rows[:, j].astype(jnp.float32)
+        r = r if weights is None else weights[:, j, None] * r
+        out = r if out is None else out + r
+    return out
+
+
+@jax.custom_vjp
+def _dispatch(xf, order, inverse):
+    """Token rows in assignment order: ``xf[order // k]`` for the sorted
+    assignments (``order``, a permutation of the N·k assignments, and its
+    ``inverse``). The backward gathers the rows' gradients back into token
+    order and sums each token's k: no scatter-add."""
+    return xf[order // (order.shape[0] // xf.shape[0])]
+
+
+def _dispatch_fwd(xf, order, inverse):
+    return _dispatch(xf, order, inverse), (order, inverse, xf.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    order, inverse, n = res
+    return _sum_k(g[inverse].reshape(n, -1, g.shape[-1])).astype(g.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, w, order, inverse):
+    """Each token's k expert rows, weighted and summed: y (N·k, D) in
+    assignment order, w (N, k) the routing weights -> (N, D), summed in
+    float32 and cast to y's dtype. The backward gathers too: row j's
+    gradient is its token's, times its weight."""
+    n, k = w.shape
+    return _sum_k(y[inverse].reshape(n, k, -1), w).astype(y.dtype)
+
+
+def _combine_fwd(y, w, order, inverse):
+    return _combine(y, w, order, inverse), (y, w, order, inverse)
+
+
+def _combine_bwd(res, g):
+    y, w, order, inverse = res
+    n, k = w.shape
+    g_rows = g[order // k].astype(jnp.float32)      # each row's token's gradient
+    dy = (g_rows * w.reshape(-1)[order][:, None]).astype(y.dtype)
+    dw = jnp.sum(g_rows * y.astype(jnp.float32), axis=-1)[inverse].reshape(n, k)
+    return dy, dw, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _grouped_experts(xf, topw, topi, w_gate, w_up, w_down, expert_offset):
+    """Every assignment to a held expert, computed: no capacity, no drop.
+
+    The N·k assignments are sorted by held expert, the others last; the
+    first ``M = N·min(k, E_l)`` sorted rows (as many as the held experts can
+    be given, one token being routed to each expert at most once) go
+    through the three grouped matmuls; the weighted rows return to token
+    order by the inverse permutation and are summed per token. Rows past
+    the held experts' groups are masked on the way in and out, as the
+    grouped matmul leaves them unwritten.
+
+    Returns ((N, D) in ``xf``'s dtype, rows (2,) int32: the rows computed
+    here and the largest held expert's rows)."""
+    N, D = xf.shape
+    k = topi.shape[1]
+    E_l = w_gate.shape[0]
+    Nk, M = N * k, N * min(k, E_l)
+    flat_e = topi.reshape(Nk)
+    local = (flat_e >= expert_offset) & (flat_e < expert_offset + E_l)
+    le = jnp.where(local, flat_e - expert_offset, E_l)
+    order = jnp.argsort(le, stable=True).astype(jnp.int32)
+    inverse = jnp.zeros((Nk,), jnp.int32).at[order].set(
+        jnp.arange(Nk, dtype=jnp.int32), unique_indices=True)
+    sizes = jnp.bincount(le, length=E_l + 1)[:E_l].astype(jnp.int32)
+    valid = (jnp.arange(M) < jnp.sum(sizes))[:, None]
+    rows = jnp.where(valid, _dispatch(xf, order, inverse)[:M], jnp.zeros((), xf.dtype))
+    h = (jax.nn.silu(grouped_matmul(rows, w_gate.astype(xf.dtype), sizes))
+         * grouped_matmul(rows, w_up.astype(xf.dtype), sizes))
+    y = jnp.where(valid, grouped_matmul(h, w_down.astype(xf.dtype), sizes),
+                  jnp.zeros((), xf.dtype))
+    out = _combine(jnp.pad(y, ((0, Nk - M), (0, 0))), topw, order, inverse)
+    return out, jnp.stack([jnp.sum(sizes), jnp.max(sizes)])
+
+
+def moe_apply_local(params, x, *, top_k: int, scoring: str = "softmax",
+                    renormalize: bool = True, routed_scale: float = 1.0,
+                    expert_offset=0):
+    """Single-device routed MoE, drop-free: x (B, S, D) -> (out, aux_loss,
+    rows), ``rows`` as :func:`_grouped_experts` gives it. The router's
+    width is the number of experts routed over; ``w_*`` hold the experts
+    from ``expert_offset`` on."""
     B, S, D = x.shape
     xf = x.reshape(B * S, D)
-    E_total = n_experts_total or params["w_gate"].shape[0]
-    topw, topi, aux = _route(xf, params["w_router"], top_k, renormalize)
-    C = capacity if capacity is not None else moe_capacity(
-        B * S, top_k, E_total, capacity_factor, min_capacity)
-    out = _dispatch_compute(xf, topw.astype(xf.dtype), topi,
-                            params["w_gate"], params["w_up"], params["w_down"],
-                            expert_offset, E_total, C)
-    return out.reshape(B, S, D), aux
+    with jax.named_scope("moe/route"):
+        topw, topi, aux = _route(xf, params, top_k, scoring, renormalize, routed_scale)
+    with jax.named_scope("moe/experts"):
+        out, rows = _grouped_experts(xf, topw, topi, params["w_gate"], params["w_up"],
+                                     params["w_down"], expert_offset)
+    return out.reshape(B, S, D), aux, rows
 
 
 def moe_apply_sharded(params, x, *, mesh, top_k: int,
                       data_axes=("data",), model_axis: str = "model",
                       ff_shard_axis: Optional[str] = "data",
                       capacity_factor: float = 1.25, min_capacity: int = 4,
-                      renormalize: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                      scoring: str = "softmax", renormalize: bool = True,
+                      routed_scale: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Expert-parallel MoE over ``mesh`` (see module docstring).
 
     x is sharded (batch over data_axes, replicated over model_axis); expert
@@ -170,14 +299,16 @@ def moe_apply_sharded(params, x, *, mesh, top_k: int,
     n_local = (B // dp) * S
     C = moe_capacity(n_local, top_k, n_experts, capacity_factor, min_capacity)
 
-    def local_fn(x_l, w_router, w_gate, w_up, w_down):
+    router = {k: params[k] for k in ("w_router", "e_score_correction_bias") if k in params}
+
+    def local_fn(x_l, router, w_gate, w_up, w_down):
         if ff_axis is not None:
             w_gate = jax.lax.all_gather(w_gate, ff_axis, axis=2, tiled=True)
             w_up = jax.lax.all_gather(w_up, ff_axis, axis=2, tiled=True)
             w_down = jax.lax.all_gather(w_down, ff_axis, axis=1, tiled=True)
         Bl, Sl, Dl = x_l.shape
         xf = x_l.reshape(Bl * Sl, Dl)
-        topw, topi, aux = _route(xf, w_router, top_k, renormalize)
+        topw, topi, aux = _route(xf, router, top_k, scoring, renormalize, routed_scale)
         off = jax.lax.axis_index(model_axis) * E_l
         out = _dispatch_compute(xf, topw.astype(xf.dtype), topi,
                                 w_gate, w_up, w_down, off, n_experts, C)
@@ -188,24 +319,25 @@ def moe_apply_sharded(params, x, *, mesh, top_k: int,
     out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
-        in_specs=(batch_spec, P(None, None), gate_spec, gate_spec, down_spec),
+        in_specs=(batch_spec, jax.tree.map(lambda _: P(), router), gate_spec,
+                  gate_spec, down_spec),
         out_specs=(batch_spec, P()),
         check_vma=False,
-    )(x, params["w_router"], params["w_gate"], params["w_up"], params["w_down"])
+    )(x, router, params["w_gate"], params["w_up"], params["w_down"])
     return out, aux
 
 
-def moe_reference(params, x, *, top_k: int, renormalize: bool = True):
-    """Exact dense oracle: every expert computed for every token, masked by
-    the router's top-k choice.  O(E) cost — tests only."""
+def moe_reference(params, x, *, top_k: int, scoring: str = "softmax",
+                  renormalize: bool = True, routed_scale: float = 1.0,
+                  expert_offset: int = 0):
+    """Exact dense oracle: every held expert computed for every token,
+    masked by the router's top-k choice.  O(E) cost — tests only."""
     B, S, D = x.shape
     xf = x.reshape(B * S, D)
-    topw, topi, aux = _route(xf, params["w_router"], top_k, renormalize)
-    E = params["w_gate"].shape[0]
-    out = jnp.zeros_like(xf)
-    for e in range(E):
-        w_e = jnp.sum(jnp.where(topi == e, topw, 0.0), axis=1)  # (N,)
+    topw, topi, aux = _route(xf, params, top_k, scoring, renormalize, routed_scale)
+    out = jnp.zeros(xf.shape, jnp.float32)
+    for e in range(params["w_gate"].shape[0]):
+        w_e = jnp.sum(jnp.where(topi == expert_offset + e, topw, 0.0), axis=1)  # (N,)
         h = jax.nn.silu(xf @ params["w_gate"][e]) * (xf @ params["w_up"][e])
-        y = h @ params["w_down"][e]
-        out = out + w_e[:, None].astype(xf.dtype) * y
-    return out.reshape(B, S, D), aux
+        out = out + w_e[:, None] * (h @ params["w_down"][e]).astype(jnp.float32)
+    return out.astype(xf.dtype).reshape(B, S, D), aux

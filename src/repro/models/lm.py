@@ -2,16 +2,21 @@
 
 A model is a cycle of *block types* (``block_pattern``) over ``n_layers``:
 
-* ``dense``      — GQA attention + SwiGLU MLP (granite, qwen3, internlm2,
+* ``dense``      — attention + SwiGLU MLP (granite, qwen3, internlm2,
                    VLM/audio backbones)
-* ``moe``        — GQA attention + routed MoE (+ optional shared experts)
+* ``moe``        — attention + routed MoE (+ optional shared experts)
 * ``ssd``        — Mamba-2 SSD block (attention-free)
 * ``rglru``      — RG-LRU temporal mixing + MLP (RecurrentGemma)
 * ``local_attn`` — sliding-window GQA + MLP (RecurrentGemma's 1:2 pattern)
 
-Layers are grouped into ``lax.scan``-stacked *super-blocks* (one pattern
-period per step) so the compiled HLO is O(1) in depth; the remainder layers
-(``n_layers % len(pattern)``) run unrolled.  Caches mirror the grouping.
+Attention is GQA, or multi-head latent attention (``layers.mla``) where the
+config gives a ``kv_lora_rank``, in every layer.  ``first_k_dense`` leading
+``dense`` layers (DeepSeek-V3's ``first_k_dense_replace``) run unrolled
+before the pattern starts.  The pattern's layers are grouped into
+``lax.scan``-stacked *super-blocks* (one pattern period per step) so the
+compiled HLO is O(1) in depth; the remainder layers run unrolled.  Caches
+mirror the grouping (the leading layers and latent attention have none:
+training and prefill without a cache only).
 """
 from __future__ import annotations
 
@@ -23,6 +28,7 @@ import jax.numpy as jnp
 
 from ..layers.attention import attention_axes, attn_forward, init_attention, init_kv_cache
 from ..layers.mlp import init_mlp, mlp_axes, mlp_forward
+from ..layers.mla import init_mla, mla_axes, mla_forward
 from ..layers.moe import init_moe, moe_apply_local, moe_apply_sharded, moe_axes
 from ..layers.norms import init_rmsnorm, rmsnorm, rmsnorm_axes
 from ..layers.tp_block import tp_attn_sublayer, tp_mlp_sublayer, tp_rglru_sublayer
@@ -46,13 +52,23 @@ class LMConfig:
     qk_norm: bool = False
     rope_theta: float = 10_000.0
     tie_embeddings: bool = False
+    norm_eps: float = 1e-6            # every RMSNorm's epsilon (rms_norm_eps)
+    first_k_dense: int = 0            # leading dense layers (first_k_dense_replace)
+    # latent attention (MLA) in place of GQA where kv_lora_rank > 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0                # experts the router scores (n_routed_experts)
     top_k: int = 0
     d_ff_expert: int = 0
     n_shared_experts: int = 0
-    moe_capacity_factor: float = 1.25
-    moe_renormalize: bool = True
+    moe_capacity_factor: float = 1.25   # the sharded path's capacity only
+    moe_renormalize: bool = True        # norm_topk_prob
+    moe_scoring: str = "softmax"        # scoring_func: softmax | sigmoid (noaux_tc)
+    moe_routed_scale: float = 1.0       # routed_scaling_factor
+    n_experts_held: int = 0             # experts held here (0: all), from 0 on
     # SSM (Mamba-2)
     ssm_state: int = 0
     ssm_headdim: int = 64
@@ -91,15 +107,20 @@ class LMConfig:
     @property
     def layer_types(self) -> Tuple[str, ...]:
         m = len(self.block_pattern)
-        return tuple(self.block_pattern[i % m] for i in range(self.n_layers))
+        return ("dense",) * self.first_k_dense + tuple(
+            self.block_pattern[i % m] for i in range(self.n_layers - self.first_k_dense))
 
     @property
     def n_super(self) -> int:
-        return self.n_layers // len(self.block_pattern)
+        return (self.n_layers - self.first_k_dense) // len(self.block_pattern)
 
     @property
     def n_tail(self) -> int:
-        return self.n_layers % len(self.block_pattern)
+        return (self.n_layers - self.first_k_dense) % len(self.block_pattern)
+
+    @property
+    def tail_types(self) -> Tuple[str, ...]:
+        return self.layer_types[self.first_k_dense + self.n_super * len(self.block_pattern):]
 
     @property
     def is_recurrent_only(self) -> bool:
@@ -111,24 +132,38 @@ class LMConfig:
 
 
 # ------------------------------------------------------------------ builders
+def _init_attn(cfg: LMConfig, key) -> Dict:
+    if cfg.kv_lora_rank:
+        return init_mla(key, cfg.d_model, cfg.n_heads, cfg.kv_lora_rank,
+                        cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim,
+                        cfg.param_dtype)
+    return init_attention(key, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
+                          cfg.qk_norm, cfg.param_dtype)
+
+
+def _attn_axes(cfg: LMConfig) -> Dict:
+    return mla_axes() if cfg.kv_lora_rank else attention_axes(cfg.qk_norm)
+
+
 def _init_block(cfg: LMConfig, key, btype: str) -> Dict:
+    """Keys: one split of ``key`` in four; the attention takes the first,
+    the MLP or the routed experts the second, the shared experts the third."""
     ks = jax.random.split(key, 4)
     dt = cfg.param_dtype
     if btype in ("dense", "local_attn"):
         return {
             "ln1": init_rmsnorm(cfg.d_model, dt),
-            "attn": init_attention(ks[0], cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
-                                   cfg.hd, cfg.qk_norm, dt),
+            "attn": _init_attn(cfg, ks[0]),
             "ln2": init_rmsnorm(cfg.d_model, dt),
             "mlp": init_mlp(ks[1], cfg.d_model, cfg.d_ff, dt, gated=cfg.mlp_gated),
         }
     if btype == "moe":
         p = {
             "ln1": init_rmsnorm(cfg.d_model, dt),
-            "attn": init_attention(ks[0], cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
-                                   cfg.hd, cfg.qk_norm, dt),
+            "attn": _init_attn(cfg, ks[0]),
             "ln2": init_rmsnorm(cfg.d_model, dt),
-            "moe": init_moe(ks[1], cfg.d_model, cfg.d_ff_expert, cfg.n_experts, dt),
+            "moe": init_moe(ks[1], cfg.d_model, cfg.d_ff_expert, cfg.n_experts, dt,
+                            n_held=cfg.n_experts_held or None, scoring=cfg.moe_scoring),
         }
         if cfg.n_shared_experts:
             p["shared"] = init_mlp(ks[2], cfg.d_model,
@@ -154,11 +189,11 @@ def _init_block(cfg: LMConfig, key, btype: str) -> Dict:
 
 def _block_axes(cfg: LMConfig, btype: str) -> Dict:
     if btype in ("dense", "local_attn"):
-        return {"ln1": rmsnorm_axes(), "attn": attention_axes(cfg.qk_norm),
+        return {"ln1": rmsnorm_axes(), "attn": _attn_axes(cfg),
                 "ln2": rmsnorm_axes(), "mlp": mlp_axes(cfg.mlp_gated)}
     if btype == "moe":
-        ax = {"ln1": rmsnorm_axes(), "attn": attention_axes(cfg.qk_norm),
-              "ln2": rmsnorm_axes(), "moe": moe_axes()}
+        ax = {"ln1": rmsnorm_axes(), "attn": _attn_axes(cfg),
+              "ln2": rmsnorm_axes(), "moe": moe_axes(cfg.moe_scoring)}
         if cfg.n_shared_experts:
             ax["shared"] = mlp_axes()
         return ax
@@ -185,6 +220,9 @@ def _stack(trees: List) -> Any:
 
 
 def init_params(cfg: LMConfig, key) -> Dict:
+    """Keys: ``key`` split in four: the embedding; the scanned super-blocks
+    (split once per super-block); the leading and the remainder layers
+    (split once per layer, the leading ones first); the output head."""
     k_emb, k_scan, k_tail, k_head = jax.random.split(key, 4)
     params: Dict[str, Any] = {}
     if cfg.input_mode in ("tokens", "prefix_embeds"):
@@ -193,10 +231,14 @@ def init_params(cfg: LMConfig, key) -> Dict:
     if cfg.n_super > 0:
         ks = jax.random.split(k_scan, cfg.n_super)
         params["scan"] = _stack([_init_super(cfg, k) for k in ks])
-    tail_types = cfg.layer_types[cfg.n_super * len(cfg.block_pattern):]
-    if tail_types:
-        ks = jax.random.split(k_tail, len(tail_types))
-        params["tail"] = [_init_block(cfg, ks[i], t) for i, t in enumerate(tail_types)]
+    unrolled = ("dense",) * cfg.first_k_dense + cfg.tail_types
+    if unrolled:
+        ks = jax.random.split(k_tail, len(unrolled))
+        blocks = [_init_block(cfg, ks[i], t) for i, t in enumerate(unrolled)]
+        if cfg.first_k_dense:
+            params["lead"] = blocks[:cfg.first_k_dense]
+        if cfg.tail_types:
+            params["tail"] = blocks[cfg.first_k_dense:]
     params["final_norm"] = init_rmsnorm(cfg.d_model, cfg.param_dtype)
     if not cfg.tie_embeddings:
         params["lm_head"] = (jax.random.normal(k_head, (cfg.d_model, cfg.vocab_size))
@@ -210,14 +252,15 @@ def param_axes(cfg: LMConfig) -> AxTree:
     ax: Dict[str, Any] = {}
     if cfg.input_mode in ("tokens", "prefix_embeds"):
         ax["embed"] = ("vocab", "embed")
+    if cfg.first_k_dense:
+        ax["lead"] = [_block_axes(cfg, "dense")] * cfg.first_k_dense
     if cfg.n_super > 0:
         sup = _super_axes(cfg)
         ax["scan"] = jax.tree.map(
             lambda t: ("layers",) + t, sup,
             is_leaf=lambda x: isinstance(x, tuple))
-    tail_types = cfg.layer_types[cfg.n_super * len(cfg.block_pattern):]
-    if tail_types:
-        ax["tail"] = [_block_axes(cfg, t) for t in tail_types]
+    if cfg.tail_types:
+        ax["tail"] = [_block_axes(cfg, t) for t in cfg.tail_types]
     ax["final_norm"] = rmsnorm_axes()
     if not cfg.tie_embeddings:
         ax["lm_head"] = ("embed", "vocab")
@@ -248,26 +291,29 @@ def init_caches(cfg: LMConfig, batch: int, cache_len: int) -> Dict:
                for i, t in enumerate(cfg.block_pattern)}
         caches["scan"] = jax.tree.map(
             lambda x: jnp.broadcast_to(x, (cfg.n_super,) + x.shape), one)
-    tail_types = cfg.layer_types[cfg.n_super * len(cfg.block_pattern):]
-    if tail_types:
+    if cfg.tail_types:
         caches["tail"] = [_init_block_cache(cfg, t, batch, cache_len)
-                          for t in tail_types]
+                          for t in cfg.tail_types]
     return caches
 
 
 # ------------------------------------------------------------------- forward
 def _apply_block(cfg: LMConfig, p, x, btype: str, *, cache, pos_offset,
                  make_cache_len, mesh):
-    """Returns (x, new_cache, aux)."""
+    """Returns (x, new_cache, aux, rows): ``rows`` is what the single-device
+    MoE layer counts (``moe_apply_local``), None for any other block."""
     aux = jnp.zeros((), jnp.float32)
+    rows = None
+    eps = cfg.norm_eps
     if btype in ("dense", "local_attn", "moe"):
+        scores_dtype = jnp.bfloat16 if cfg.attn_scores_bf16 else jnp.float32
         window = cfg.local_window if btype == "local_attn" else None
         mcl = make_cache_len
         if btype == "local_attn" and mcl is not None:
             mcl = min(mcl, cfg.local_window)
         tp = mesh.shape.get("model", 1) if mesh is not None else 1
         use_tp = (cfg.tp_block == "shard_map" and tp > 1
-                  and cache is None and mcl is None
+                  and cache is None and mcl is None and not cfg.kv_lora_rank
                   and cfg.n_heads % tp == 0)  # heads must divide the TP axis
         if use_tp:
             data_axes = tuple(a for a in ("pod", "data")
@@ -279,48 +325,58 @@ def _apply_block(cfg: LMConfig, p, x, btype: str, *, cache, pos_offset,
             if btype != "moe" and cfg.d_ff % tp == 0:
                 x = tp_mlp_sublayer(p["ln2"], p["mlp"], x, cfg=cfg, mesh=mesh,
                                     data_axes=data_axes)
-                return x, None, aux
+                return x, None, aux, rows
             if btype != "moe":
-                x = x + mlp_forward(p["mlp"], rmsnorm(p["ln2"], x))
-                return x, None, aux
+                x = x + mlp_forward(p["mlp"], rmsnorm(p["ln2"], x, eps))
+                return x, None, aux, rows
+        elif cfg.kv_lora_rank:
+            if cache is not None or mcl is not None:
+                raise NotImplementedError("latent attention keeps no KV cache")
+            x = x + mla_forward(
+                p["attn"], rmsnorm(p["ln1"], x, eps), n_heads=cfg.n_heads,
+                kv_lora_rank=cfg.kv_lora_rank, qk_nope_dim=cfg.qk_nope_dim,
+                qk_rope_dim=cfg.qk_rope_dim, v_head_dim=cfg.v_head_dim,
+                rope_theta=cfg.rope_theta, norm_eps=eps, impl=cfg.attn_impl,
+                chunk=cfg.attn_chunk, unroll=cfg.unroll_scans,
+                scores_dtype=scores_dtype, mesh=mesh)
+            new_cache = None
         else:
             a, new_cache = attn_forward(
-                p["attn"], rmsnorm(p["ln1"], x),
+                p["attn"], rmsnorm(p["ln1"], x, eps),
                 n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd,
                 rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm, window=window,
                 pos_offset=pos_offset, cache=cache, make_cache_len=mcl,
                 cache_dtype=cfg.cache_dtype, impl=cfg.attn_impl,
                 chunk=cfg.attn_chunk, unroll=cfg.unroll_scans,
-                scores_dtype=jnp.bfloat16 if cfg.attn_scores_bf16
-                else jnp.float32, mesh=mesh)
+                scores_dtype=scores_dtype, mesh=mesh)
             x = x + a
-        h = rmsnorm(p["ln2"], x)
+        h = rmsnorm(p["ln2"], x, eps)
         if btype == "moe":
+            route = dict(top_k=cfg.top_k, scoring=cfg.moe_scoring,
+                         renormalize=cfg.moe_renormalize,
+                         routed_scale=cfg.moe_routed_scale)
             if mesh is not None and mesh.shape.get("model", 1) > 1:
                 routed, aux = moe_apply_sharded(
-                    p["moe"], h, mesh=mesh, top_k=cfg.top_k,
+                    p["moe"], h, mesh=mesh,
                     data_axes=tuple(a for a in ("pod", "data") if a in mesh.axis_names),
                     model_axis="model", ff_shard_axis=cfg.moe_ff_shard_axis,
-                    capacity_factor=cfg.moe_capacity_factor,
-                    renormalize=cfg.moe_renormalize)
+                    capacity_factor=cfg.moe_capacity_factor, **route)
             else:
-                routed, aux = moe_apply_local(
-                    p["moe"], h, top_k=cfg.top_k,
-                    capacity_factor=cfg.moe_capacity_factor,
-                    renormalize=cfg.moe_renormalize)
+                routed, aux, rows = moe_apply_local(p["moe"], h, **route)
             y = routed
             if cfg.n_shared_experts:
-                y = y + mlp_forward(p["shared"], h)
+                with jax.named_scope("moe/shared"):
+                    y = y + mlp_forward(p["shared"], h)
         else:
             y = mlp_forward(p["mlp"], h)
-        return x + y, new_cache, aux
+        return x + y, new_cache, aux, rows
     if btype == "ssd":
         y, new_cache = ssd_forward(
-            p["ssd"], rmsnorm(p["ln1"], x), expand=cfg.ssm_expand,
+            p["ssd"], rmsnorm(p["ln1"], x, eps), expand=cfg.ssm_expand,
             headdim=cfg.ssm_headdim, d_state=cfg.ssm_state,
             conv_width=cfg.conv_width, chunk=cfg.ssm_chunk, cache=cache,
             make_cache=make_cache_len is not None, unroll=cfg.unroll_scans)
-        return x + y, new_cache, aux
+        return x + y, new_cache, aux, rows
     if btype == "rglru":
         tp = mesh.shape.get("model", 1) if mesh is not None else 1
         use_tp = (cfg.tp_block == "shard_map" and tp > 1 and cache is None
@@ -334,28 +390,33 @@ def _apply_block(cfg: LMConfig, p, x, btype: str, *, cache, pos_offset,
                                   data_axes=data_axes)
             x = tp_mlp_sublayer(p["ln2"], p["mlp"], x, cfg=cfg, mesh=mesh,
                                 data_axes=data_axes)
-            return x, None, aux
-        y, new_cache = rglru_forward(p["rec"], rmsnorm(p["ln1"], x), cache=cache,
+            return x, None, aux, rows
+        y, new_cache = rglru_forward(p["rec"], rmsnorm(p["ln1"], x, eps), cache=cache,
                                      make_cache=make_cache_len is not None)
         x = x + y
-        x = x + mlp_forward(p["mlp"], rmsnorm(p["ln2"], x))
-        return x, new_cache, aux
+        x = x + mlp_forward(p["mlp"], rmsnorm(p["ln2"], x, eps))
+        return x, new_cache, aux, rows
     raise ValueError(btype)
 
 
 def _apply_super(cfg: LMConfig, p_sb, x, caches_sb, *, pos_offset,
                  make_cache_len, mesh):
+    """Returns (x, new_caches, aux, rows): ``rows`` the MoE layers' counts
+    stacked, (n, 2), or None where no block counts."""
     new_caches = {}
     aux_total = jnp.zeros((), jnp.float32)
+    rows = []
     for i, btype in enumerate(cfg.block_pattern):
         c = caches_sb.get(f"b{i}") if caches_sb else None
-        x, nc, aux = _apply_block(cfg, p_sb[f"b{i}"], x, btype, cache=c,
-                                  pos_offset=pos_offset,
-                                  make_cache_len=make_cache_len, mesh=mesh)
+        x, nc, aux, r = _apply_block(cfg, p_sb[f"b{i}"], x, btype, cache=c,
+                                     pos_offset=pos_offset,
+                                     make_cache_len=make_cache_len, mesh=mesh)
         aux_total = aux_total + aux
         if nc is not None:
             new_caches[f"b{i}"] = nc
-    return x, (new_caches or None), aux_total
+        if r is not None:
+            rows.append(r)
+    return x, (new_caches or None), aux_total, (jnp.stack(rows) if rows else None)
 
 
 def embed_inputs(cfg: LMConfig, params, batch) -> jnp.ndarray:
@@ -375,16 +436,50 @@ def embed_inputs(cfg: LMConfig, params, batch) -> jnp.ndarray:
     return x.astype(cfg.compute_dtype)
 
 
+def _remat(fn, remat: str):
+    """``fn`` rematerialised as ``remat`` says: ``full`` keeps only its
+    inputs for the backward, ``dots`` its matmul outputs too."""
+    if remat == "full":
+        return jax.checkpoint(fn)
+    if remat == "dots":
+        return jax.checkpoint(fn, policy=jax.checkpoint_policies.checkpoint_dots)
+    return fn
+
+
 def forward(cfg: LMConfig, params, batch, *, caches=None, pos_offset=0,
             make_cache_len: Optional[int] = None, mesh=None,
             remat: Optional[str] = None, last_only: bool = False):
-    """Returns (logits fp32 (B,S,V), new_caches or None, aux_loss).
+    """Returns (logits fp32 (B,S,V), new_caches or None, aux): ``aux["balance"]``
+    the summed load-balance loss, and ``aux["moe_rows"]`` where single-device
+    MoE layers ran: per MoE layer in order, the rows its held experts
+    computed and the largest held expert's rows, (n_moe, 2) int32.
     ``last_only=True`` computes logits for the final position only (prefill
     memory saver: avoids materializing (B, S, V))."""
     remat = cfg.remat if remat is None else remat
+    if cfg.first_k_dense and (caches is not None or make_cache_len is not None):
+        raise NotImplementedError("the leading dense layers keep no KV cache")
     x = embed_inputs(cfg, params, batch)
     aux_total = jnp.zeros((), jnp.float32)
     new_caches: Dict[str, Any] = {}
+    rows: List[Any] = []
+
+    def unrolled(x, aux_total, blocks, types, layer_caches):
+        out_caches = []
+        for p, btype, c in zip(blocks, types, layer_caches):
+            block = _remat(lambda p, x, btype=btype, c=c: _apply_block(
+                cfg, p, x, btype, cache=c, pos_offset=pos_offset,
+                make_cache_len=make_cache_len, mesh=mesh), remat)
+            x, nc, a, r = block(p, x)
+            aux_total = aux_total + a
+            out_caches.append(nc)
+            if r is not None:
+                rows.append(r[None])
+        return x, aux_total, out_caches
+
+    if cfg.first_k_dense:
+        x, aux_total, _ = unrolled(x, aux_total, params["lead"],
+                                   ("dense",) * cfg.first_k_dense,
+                                   [None] * cfg.first_k_dense)
 
     if cfg.n_super > 0:
         scan_caches = caches.get("scan") if caches else None
@@ -392,58 +487,54 @@ def forward(cfg: LMConfig, params, batch, *, caches=None, pos_offset=0,
         def body(carry, xs):
             x, aux = carry
             p_sb, c_sb = xs
-            x, nc, a = _apply_super(cfg, p_sb, x, c_sb, pos_offset=pos_offset,
-                                    make_cache_len=make_cache_len, mesh=mesh)
-            return (x, aux + a), nc
+            x, nc, a, r = _apply_super(cfg, p_sb, x, c_sb, pos_offset=pos_offset,
+                                       make_cache_len=make_cache_len, mesh=mesh)
+            return (x, aux + a), (nc, r)
 
-        if remat == "full":
-            body = jax.checkpoint(body)
-        elif remat == "dots":
-            body = jax.checkpoint(
-                body, policy=jax.checkpoint_policies.checkpoint_dots)
+        body = _remat(body, remat)
         if cfg.scan_layers:
-            (x, aux_total), nc_scan = jax.lax.scan(
+            (x, aux_total), (nc_scan, r_scan) = jax.lax.scan(
                 body, (x, aux_total), (params["scan"], scan_caches))
         else:
             # dry-run probe path: python loop so every layer's cost is in HLO
-            ncs = []
+            outs = []
             for i in range(cfg.n_super):
                 p_sb = jax.tree.map(lambda a: a[i], params["scan"])
                 c_sb = (jax.tree.map(lambda a: a[i], scan_caches)
                         if scan_caches is not None else None)
-                (x, aux_total), nc = body((x, aux_total), (p_sb, c_sb))
-                ncs.append(nc)
-            nc_scan = (jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
-                       if ncs and ncs[0] is not None else None)
+                (x, aux_total), out = body((x, aux_total), (p_sb, c_sb))
+                outs.append(out)
+            nc_scan, r_scan = (jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+                               if outs else (None, None))
         if nc_scan is not None:
             new_caches["scan"] = nc_scan
+        if r_scan is not None:           # (n_super, moe blocks per period, 2)
+            rows.append(r_scan.reshape(-1, 2))
 
-    tail_types = cfg.layer_types[cfg.n_super * len(cfg.block_pattern):]
-    if tail_types:
-        tail_caches = (caches.get("tail") if caches else [None] * len(tail_types))
-        nc_tail = []
-        for i, btype in enumerate(tail_types):
-            x, nc, a = _apply_block(cfg, params["tail"][i], x, btype,
-                                    cache=tail_caches[i], pos_offset=pos_offset,
-                                    make_cache_len=make_cache_len, mesh=mesh)
-            aux_total = aux_total + a
-            nc_tail.append(nc)
+    if cfg.tail_types:
+        tail_caches = (caches.get("tail") if caches else [None] * len(cfg.tail_types))
+        x, aux_total, nc_tail = unrolled(x, aux_total, params["tail"], cfg.tail_types,
+                                         tail_caches)
         if any(c is not None for c in nc_tail):
             new_caches["tail"] = nc_tail
 
     if last_only:
         x = x[:, -1:]
-    x = rmsnorm(params["final_norm"], x)
+    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     with jax.named_scope("logits"):
         logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
-    return logits, (new_caches or None), aux_total
+    aux = {"balance": aux_total}
+    if rows:
+        aux["moe_rows"] = jnp.concatenate(rows)
+    return logits, (new_caches or None), aux
 
 
 def loss_fn(cfg: LMConfig, params, batch, *, mesh=None, aux_weight: float = 0.01,
             remat: Optional[str] = None):
     """Masked next-token cross entropy.  batch must carry ``targets`` (B,S)
-    and ``loss_mask`` (B,S) aligned with the model's output positions."""
+    and ``loss_mask`` (B,S) aligned with the model's output positions.
+    The metrics carry ``moe_rows`` where :func:`forward` gives it."""
     logits, _, aux = forward(cfg, params, batch, mesh=mesh, remat=remat)
     targets = batch["targets"]
     mask = batch["loss_mask"].astype(jnp.float32)
@@ -453,5 +544,8 @@ def loss_fn(cfg: LMConfig, params, batch, *, mesh=None, aux_weight: float = 0.01
         nll = (logz - gold) * mask
         denom = jnp.maximum(jnp.sum(mask), 1.0)
         loss = jnp.sum(nll) / denom
-    total = loss + aux_weight * aux
-    return total, {"loss": loss, "aux": aux, "tokens": jnp.sum(mask)}
+    total = loss + aux_weight * aux["balance"]
+    metrics = {"loss": loss, "aux": aux["balance"], "tokens": jnp.sum(mask)}
+    if "moe_rows" in aux:
+        metrics["moe_rows"] = aux["moe_rows"]
+    return total, metrics

@@ -1,6 +1,7 @@
 """Compile the main-path kernels (the fused attention forward and backward
-among them) and the qwen3-0.6b decode step for a described TPU v5e,
-without a chip.
+among them), the qwen3-0.6b decode step and the train step of the
+benchmark's latent-attention MoE cell for a described TPU v5e, without a
+chip.
 
 Interpret mode (the CPU tests) cannot see what Mosaic refuses: unsupported
 primitives, tile-misaligned slices, scalar stores to VMEM, VMEM overflow.
@@ -12,6 +13,8 @@ about results or times.
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
+import dataclasses
+import functools
 import os
 
 import jax
@@ -26,6 +29,7 @@ from repro.kernels.kmeans_assign import kmeans_assign
 from repro.kernels.knn_topk import knn_topk
 from repro.kernels.rmsnorm import rmsnorm
 from repro.launch.mesh import auto_mesh
+from repro.layers import attention
 from repro.models.lm import init_params
 
 
@@ -133,3 +137,36 @@ def test_qwen3_decode_step_compiles_for_v5e_at_full_width(topo):
     # plus the bf16 KV cache (0.50 GB)
     assert 1.0e9 < mem.argument_size_in_bytes < 2.0e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_moonlight_cell_train_step_compiles_for_v5e(topo, monkeypatch):
+    """The whole train step of the benchmark cell ``train-moonlight-s8k``:
+    moonlight-16b-a3b at its published widths on one chip's share (the
+    dense layer and 4 MoE layers, 8 of 64 experts held, 20,480 vocabulary
+    rows), 4 x 8192 tokens, AdamW, full remat. Latent attention takes the
+    splash kernels at query/key width 192 and value width 128, the held
+    experts megablox's grouped matmuls; the step fits the chip's 15.75 GB
+    (5.69 GB of arguments and 9.62 GB of temporaries; a fifth MoE layer
+    does not fit)."""
+    from repro.distributed.steps import make_train_step
+    from repro.optim.adamw import adamw, cosine_schedule
+    monkeypatch.setattr(ops, "_interpret", lambda: False)        # the CPU's branches
+    monkeypatch.setattr(attention, "attn_path",
+                        functools.partial(attention.attn_path, platform="tpu"))
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"), n_layers=5,
+                              n_experts_held=8, vocab_size=20480)
+    mesh = auto_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    opt = adamw(cosine_schedule(3e-4, 10, 1000), weight_decay=0.01)
+    batch = {k: jax.ShapeDtypeStruct((4, 8192), dt) for k, dt in (
+        ("tokens", jnp.int32), ("targets", jnp.int32), ("loss_mask", jnp.float32))}
+    step, in_sh, out_sh, donate = make_train_step(cfg, mesh, opt, sample_batch=batch)
+    pshapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    compiled = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh,
+                       donate_argnums=donate).lower(
+        pshapes, jax.eval_shape(opt.init, pshapes), batch).compile()
+    text = compiled.as_text()
+    assert "splash_mqa_fwd_residuals" in text and "splash_mqa_dkv" in text
+    assert "%gmm" in text and "%tgmm" in text
+    mem = compiled.memory_analysis()
+    assert 5.6e9 < mem.argument_size_in_bytes < 5.8e9      # 10 B a parameter, 569M
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
