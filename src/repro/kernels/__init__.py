@@ -7,6 +7,8 @@ pure-jnp oracle in ``ref.py`` and a jit'd wrapper in ``ops.py``:
   for training and prefill on a single TPU
 * ``flash_attention`` — the repo's own forward-only GQA causal/windowed
   flash kernel; reached only by its tests
+* ``moe_rows``        — the routed experts' row gather and per-token
+  weighted sum, over the rows below a count known at run time
 * ``ssd_scan``        — Mamba-2 SSD chunked scan
 * ``rglru_scan``      — RG-LRU linear recurrence
 * ``knn_topk``        — fused distance + running top-k (paper's KNN_frag)

@@ -15,7 +15,9 @@ single TPU (``attn_path``); the repo's own forward-only
 
 ``grouped_matmul`` multiplies row groups by their own matrices through
 JAX's Pallas megablox kernels: the routed experts of
-``layers.moe.moe_apply_local``.
+``layers.moe.moe_apply_local``, whose rows ``gather_rows_op`` and
+``combine_rows_op`` move in and out (``moe_rows``), over the rows below
+a count known at run time.
 """
 from __future__ import annotations
 
@@ -34,6 +36,7 @@ from jax.experimental.pallas.ops.tpu.splash_attention import (
 
 from .kmeans_assign import kmeans_assign
 from .knn_topk import knn_topk
+from .moe_rows import combine_rows, gather_rows
 from .rglru_scan import rglru_scan
 from .rmsnorm import rmsnorm
 from .ssd_scan import ssd_scan
@@ -126,8 +129,9 @@ def grouped_matmul(x, w, group_sizes):
     """Rows grouped by their matrix: x (M, K) with ``group_sizes[g]`` rows
     for ``w[g]`` (G, K, N), in order, -> (M, N) in x's dtype (float32
     accumulation), through megablox's ``gmm`` kernel (its backward a
-    ``gmm`` and a ``tgmm``). Rows past the groups' sum are left unwritten:
-    the caller masks them."""
+    ``gmm`` and a ``tgmm``). Rows past the groups' sum are left unwritten
+    and are not read: the backward masks them by group, and the row
+    kernels read only rows below the sum."""
     tm = _GMM_TILES[0]
     m = x.shape[0]
     pad = -m % tm
@@ -136,6 +140,15 @@ def grouped_matmul(x, w, group_sizes):
     out = megablox_gmm(x, w, group_sizes, x.dtype, _GMM_TILES, None, None, False,
                        _interpret())
     return out[:m] if pad else out
+
+
+# ------------------------------------------------------------ routed rows
+def gather_rows_op(src, idx, count, scale=None, dot_with=None):
+    return gather_rows(src, idx, count, scale, dot_with, interpret=_interpret())
+
+
+def combine_rows_op(y, pos, w, count):
+    return combine_rows(y, pos, w, count, interpret=_interpret())
 
 
 # --------------------------------------------------------------------- others

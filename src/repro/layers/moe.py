@@ -23,10 +23,14 @@ Tokens overflowing an expert's capacity ``C = ceil(N·k·cf / E)`` are dropped
 N·k assignments are sorted by expert, the held experts' rows run through
 grouped matmuls (``kernels.ops.grouped_matmul``) and the weighted rows are
 added back in token order, so every assignment is computed under any
-imbalance.  A layer that holds the experts ``expert_offset`` to
-``expert_offset + E_l`` of the router's ``E`` routes over all ``E`` and
-returns the part of the result its own experts give (expert parallelism's
-share, without the exchange).
+imbalance.  The rows are moved by kernels whose work follows how many rows
+the held experts take, a count known at run time (``kernels.moe_rows``: a
+row gather in, a per-token weighted sum out, each the other's backward),
+not by gathers over the static bound of N·min(k, E_l) rows; each traced
+call counts ``moe.impl``.  A layer that holds the experts
+``expert_offset`` to ``expert_offset + E_l`` of the router's ``E`` routes
+over all ``E`` and returns the part of the result its own experts give
+(expert parallelism's share, without the exchange).
 
 The router is ``softmax`` (top-k of the probabilities, with the Switch
 load-balance term) or ``sigmoid`` (DeepSeek-V3's ``noaux_tc``: top-k of
@@ -45,7 +49,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..kernels.ops import grouped_matmul
+from ..core import tracing
+from ..kernels.ops import combine_rows_op, gather_rows_op, grouped_matmul
 
 
 def init_moe(key, d_model: int, d_ff_expert: int, n_experts: int,
@@ -161,96 +166,90 @@ def moe_capacity(n_tokens: int, top_k: int, n_experts: int,
     return max(min_capacity, int(math.ceil(n_tokens * top_k * capacity_factor / n_experts)))
 
 
-def _sum_k(rows, weights=None):
-    """sum_j weights[:, j] * rows[:, j] over the k of (N, k, D) rows, in
-    float32, one slice at a time: no float32 copy of all N·k rows."""
-    out = None
-    for j in range(rows.shape[1]):
-        r = rows[:, j].astype(jnp.float32)
-        r = r if weights is None else weights[:, j, None] * r
-        out = r if out is None else out + r
-    return out
-
-
 @jax.custom_vjp
-def _dispatch(xf, order, inverse):
-    """Token rows in assignment order: ``xf[order // k]`` for the sorted
-    assignments (``order``, a permutation of the N·k assignments, and its
-    ``inverse``). The backward gathers the rows' gradients back into token
-    order and sums each token's k: no scatter-add."""
-    return xf[order // (order.shape[0] // xf.shape[0])]
+def _dispatch(xf, src, pos, count):
+    """Token rows in assignment order: row r is ``xf[src[r]]`` for r below
+    ``count``, the rows the held experts take (``pos`` (N, k): each
+    assignment's row). The backward sums each token's rows' gradients."""
+    return gather_rows_op(xf, src, count)
 
 
-def _dispatch_fwd(xf, order, inverse):
-    return _dispatch(xf, order, inverse), (order, inverse, xf.shape[0])
+def _dispatch_fwd(xf, src, pos, count):
+    return _dispatch(xf, src, pos, count), (pos, count)
 
 
 def _dispatch_bwd(res, g):
-    order, inverse, n = res
-    return _sum_k(g[inverse].reshape(n, -1, g.shape[-1])).astype(g.dtype), None, None
+    pos, count = res
+    return combine_rows_op(g, pos, 1.0, count), None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(y, w, order, inverse):
-    """Each token's k expert rows, weighted and summed: y (N·k, D) in
-    assignment order, w (N, k) the routing weights -> (N, D), summed in
-    float32 and cast to y's dtype. The backward gathers too: row j's
-    gradient is its token's, times its weight."""
-    n, k = w.shape
-    return _sum_k(y[inverse].reshape(n, k, -1), w).astype(y.dtype)
+def _combine(y, w, order, pos, count):
+    """Each token's expert rows, weighted and summed: y (M, D) in
+    assignment order (its first ``count`` rows), w (N, k) the routing
+    weights, ``pos`` (N, k) each assignment's row (``order``, the sorted
+    assignments) -> (N, D), summed in float32 and cast to y's dtype. The
+    backward gathers: a row's gradient is its token's, times its weight."""
+    return combine_rows_op(y, pos, w, count)
 
 
-def _combine_fwd(y, w, order, inverse):
-    return _combine(y, w, order, inverse), (y, w, order, inverse)
+def _combine_fwd(y, w, order, pos, count):
+    return _combine(y, w, order, pos, count), (y, w, order, pos, count)
 
 
 def _combine_bwd(res, g):
-    y, w, order, inverse = res
-    n, k = w.shape
-    g_rows = g[order // k].astype(jnp.float32)      # each row's token's gradient
-    dy = (g_rows * w.reshape(-1)[order][:, None]).astype(y.dtype)
-    dw = jnp.sum(g_rows * y.astype(jnp.float32), axis=-1)[inverse].reshape(n, k)
-    return dy, dw, None, None
+    y, w, order, pos, count = res
+    k, m = w.shape[1], y.shape[0]
+    src = order[:m]
+    dy, dots = gather_rows_op(g, src // k, count, scale=w.reshape(-1)[src], dot_with=y)
+    dw = jnp.where(pos < count, dots[jnp.minimum(pos, m - 1)], 0.0)
+    return dy, dw, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _sort_assignments(topi, expert_offset, n_held: int):
+    """The N·k assignments of topi (N, k), sorted by held expert and the
+    others last: (order (N·k,) the sorted assignments, pos (N, k) each
+    assignment's place in that order, sizes (n_held,) the rows of each
+    held expert)."""
+    N, k = topi.shape
+    flat_e = topi.reshape(N * k)
+    local = (flat_e >= expert_offset) & (flat_e < expert_offset + n_held)
+    le = jnp.where(local, flat_e - expert_offset, n_held)
+    order = jnp.argsort(le, stable=True).astype(jnp.int32)
+    pos = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32), unique_indices=True).reshape(N, k)
+    sizes = jnp.bincount(le, length=n_held + 1)[:n_held].astype(jnp.int32)
+    return order, pos, sizes
 
 
 def _grouped_experts(xf, topw, topi, w_gate, w_up, w_down, expert_offset):
     """Every assignment to a held expert, computed: no capacity, no drop.
 
     The N·k assignments are sorted by held expert, the others last; the
-    first ``M = N·min(k, E_l)`` sorted rows (as many as the held experts can
-    be given, one token being routed to each expert at most once) go
-    through the three grouped matmuls; the weighted rows return to token
-    order by the inverse permutation and are summed per token. Rows past
-    the held experts' groups are masked on the way in and out, as the
-    grouped matmul leaves them unwritten.
+    first ``count`` sorted rows (at most ``M = N·min(k, E_l)``, one token
+    being routed to each expert at most once) are gathered, go through the
+    three grouped matmuls and are summed back per token. The row kernels
+    move only those ``count`` rows; the rows past it in the (M, D) buffers
+    are never read (the grouped matmuls mask by group).
 
     Returns ((N, D) in ``xf``'s dtype, rows (2,) int32: the rows computed
     here and the largest held expert's rows)."""
-    N, D = xf.shape
-    k = topi.shape[1]
+    N, k = topi.shape
     E_l = w_gate.shape[0]
-    Nk, M = N * k, N * min(k, E_l)
-    flat_e = topi.reshape(Nk)
-    local = (flat_e >= expert_offset) & (flat_e < expert_offset + E_l)
-    le = jnp.where(local, flat_e - expert_offset, E_l)
-    order = jnp.argsort(le, stable=True).astype(jnp.int32)
-    inverse = jnp.zeros((Nk,), jnp.int32).at[order].set(
-        jnp.arange(Nk, dtype=jnp.int32), unique_indices=True)
-    sizes = jnp.bincount(le, length=E_l + 1)[:E_l].astype(jnp.int32)
-    valid = (jnp.arange(M) < jnp.sum(sizes))[:, None]
-    rows = jnp.where(valid, _dispatch(xf, order, inverse)[:M], jnp.zeros((), xf.dtype))
+    order, pos, sizes = _sort_assignments(topi, expert_offset, E_l)
+    count = jnp.sum(sizes)
+    rows = _dispatch(xf, order[:N * min(k, E_l)] // k, pos, count)
     h = (jax.nn.silu(grouped_matmul(rows, w_gate.astype(xf.dtype), sizes))
          * grouped_matmul(rows, w_up.astype(xf.dtype), sizes))
-    y = jnp.where(valid, grouped_matmul(h, w_down.astype(xf.dtype), sizes),
-                  jnp.zeros((), xf.dtype))
-    out = _combine(jnp.pad(y, ((0, Nk - M), (0, 0))), topw, order, inverse)
-    return out, jnp.stack([jnp.sum(sizes), jnp.max(sizes)])
+    y = grouped_matmul(h, w_down.astype(xf.dtype), sizes)
+    out = _combine(y, topw, order, pos, count)
+    return out, jnp.stack([count, jnp.max(sizes)])
 
 
 def moe_apply_local(params, x, *, top_k: int, scoring: str = "softmax",
@@ -262,6 +261,7 @@ def moe_apply_local(params, x, *, top_k: int, scoring: str = "softmax",
     from ``expert_offset`` on."""
     B, S, D = x.shape
     xf = x.reshape(B * S, D)
+    tracing.count("moe.impl", 1, impl="rows")
     with jax.named_scope("moe/route"):
         topw, topi, aux = _route(xf, params, top_k, scoring, renormalize, routed_scale)
     with jax.named_scope("moe/experts"):

@@ -176,6 +176,132 @@ def test_expert_shares_sum_to_the_uncut_layer(scoring):
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole), atol=1e-4)
 
 
+# --------------------------------------------------- the routed-row kernels
+ROWS_N, ROWS_D, ROWS_K, ROWS_E = 24, 256, 4, 16
+
+
+def _routing(held, count, seed=0):
+    """topi (N, k) over ROWS_E experts with exactly ``count`` assignments to
+    the first ``held`` (each token's experts distinct), and its sort."""
+    rng = np.random.default_rng(seed)
+    cap = min(ROWS_K, held)
+    per_token = [cap] * (count // cap) + [count % cap]
+    per_token += [0] * (ROWS_N - len(per_token))
+    topi = np.stack([rng.permutation(np.concatenate([
+        rng.permutation(held)[:h], held + rng.permutation(ROWS_E - held)[:ROWS_K - h]]))
+        for h in rng.permutation(per_token[:ROWS_N])])
+    order, pos, sizes = moe._sort_assignments(jnp.asarray(topi, jnp.int32), 0, held)
+    assert int(jnp.sum(sizes)) == count
+    return order, pos, jnp.sum(sizes), ROWS_N * cap
+
+
+def _row_case(held, fill):
+    worst = ROWS_N * min(ROWS_K, held)
+    count = {"none": 0, "one": 1, "ragged": 13, "worst": worst}[fill]
+    order, pos, count, m = _routing(held, count)
+    ks = jax.random.split(jax.random.PRNGKey(held), 4)
+    xf = jax.random.normal(ks[0], (ROWS_N, ROWS_D), jnp.bfloat16)
+    live = (jnp.arange(m) < count)[:, None]
+    # rows past the count hold what nothing may read
+    y = jnp.where(live, jax.random.normal(ks[1], (m, ROWS_D), jnp.bfloat16), jnp.nan)
+    w = jax.random.uniform(ks[2], (ROWS_N, ROWS_K))
+    g = jax.random.normal(ks[3], (ROWS_N, ROWS_D), jnp.bfloat16)
+    return order, pos, count, m, xf, y, w, g
+
+
+def _plain_combine(y, pos, w, count):
+    """sum_j [pos < count] w y[pos], float32 in the order of j, on y with its
+    rows past the count cleared."""
+    y = jnp.where((jnp.arange(y.shape[0]) < count)[:, None], y, 0).astype(jnp.float32)
+    out = 0.0
+    for j in range(pos.shape[1]):
+        live = (pos[:, j] < count)[:, None]
+        out = out + jnp.where(live, w[:, j, None] * y[jnp.minimum(pos[:, j], y.shape[0] - 1)], 0)
+    return out
+
+
+FILLS = ["none", "one", "ragged", "worst"]
+
+
+@pytest.mark.parametrize("fill", FILLS)
+@pytest.mark.parametrize("held", [2, 8])     # fewer and more held experts than k = 4
+def test_gather_rows_matches_jnp(held, fill):
+    """Rows below the count gathered (and scaled, and dotted); rows past it
+    in the last tile zeros; tiles past it never written."""
+    from repro.kernels.moe_rows import gather_rows
+    order, pos, count, m, xf, y, w, _ = _row_case(held, fill)
+    src, scale = order[:m] // ROWS_K, w.reshape(-1)[order[:m]]
+    n, tm = int(count), 8
+    ours = gather_rows(xf, src, count, tm=tm, interpret=True)
+    scaled, dots = gather_rows(xf, src, count, scale, y, tm=tm, interpret=True)
+    want = xf[src].astype(jnp.float32)
+    edge = -(-n // tm) * tm
+    np.testing.assert_array_equal(np.asarray(ours[:n]), np.asarray(xf[src][:n]))
+    assert not np.any(np.asarray(ours[n:edge], np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(scaled[:n]), np.asarray((want * scale[:, None]).astype(jnp.bfloat16)[:n]))
+    assert not np.any(np.asarray(scaled[n:edge], np.float32))
+    np.testing.assert_allclose(np.asarray(dots[:n]), np.asarray(
+        jnp.sum(want * y.astype(jnp.float32), axis=1)[:n]), rtol=1e-5, atol=1e-4)
+    assert not np.any(np.asarray(dots[n:edge]))
+
+
+@pytest.mark.parametrize("fill", FILLS)
+@pytest.mark.parametrize("held", [2, 8])
+def test_combine_rows_matches_jnp(held, fill):
+    """Only rows below the count reach the output: those past it are NaN."""
+    from repro.kernels.moe_rows import combine_rows
+    _, pos, count, _, _, y, w, _ = _row_case(held, fill)
+    ours = combine_rows(y, pos, w, count, tn=8, interpret=True)
+    assert ours.dtype == y.dtype
+    np.testing.assert_array_equal(
+        np.asarray(ours, np.float32),
+        np.asarray(_plain_combine(y, pos, w, count).astype(y.dtype), np.float32))
+
+
+@pytest.mark.parametrize("fill", FILLS)
+@pytest.mark.parametrize("held", [2, 8])
+def test_row_movement_gradients_match_jnp(held, fill):
+    """The layer's dispatch and combine, forward and every gradient, against
+    jnp on the rows below the count; the NaN rows past it (in y, and in the
+    rows' gradient) reach no output and no gradient."""
+    order, pos, count, m, xf, y, w, g = _row_case(held, fill)
+    src, n = order[:m] // ROWS_K, int(count)
+    live = (jnp.arange(m) < count)[:, None]
+
+    rows, pull = jax.vjp(lambda x: moe._dispatch(x, src, pos, count), xf)
+    np.testing.assert_array_equal(np.asarray(rows[:n]), np.asarray(xf[src][:n]))
+    g_rows = jnp.where(live, y, jnp.nan)
+    (dx,) = pull(g_rows)
+    np.testing.assert_array_equal(
+        np.asarray(dx, np.float32),
+        np.asarray(_plain_combine(g_rows, pos, jnp.ones_like(w), count).astype(dx.dtype),
+                   np.float32))
+
+    out, pull = jax.vjp(lambda y, w: moe._combine(y, w, order, pos, count), y, w)
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32),
+        np.asarray(_plain_combine(y, pos, w, count).astype(y.dtype), np.float32))
+    dy, dw = pull(g)
+    dy_r, dw_r = jax.vjp(lambda y, w: _plain_combine(y, pos, w, count).astype(y.dtype),
+                         jnp.where(live, y, 0), w)[1](g)
+    np.testing.assert_array_equal(np.asarray(dy[:n], np.float32),
+                                  np.asarray(dy_r[:n], np.float32))
+    assert np.all(np.isfinite(np.asarray(dw)))
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_r), rtol=1e-4, atol=1e-3)
+
+
+def test_moe_impl_counted_once_per_traced_call(monkeypatch):
+    ring = TaskStream(capacity=64)
+    monkeypatch.setattr(tracing, "SPANS", ring)
+    p = _router("sigmoid")
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 32))
+    step = jax.jit(lambda p, x: moe.moe_apply_local(p, x, **ROUTE["sigmoid"])[0])
+    step(p, x)
+    step(p, x)                   # compiled once: not traced again
+    assert [r["args"] for r in ring.since(0) if r["name"] == "moe.impl"] == [{"impl": "rows"}]
+
+
 def test_held_experts_are_the_first_of_the_full_draw():
     full = moe.init_moe(jax.random.PRNGKey(3), 32, 24, 16, scoring="sigmoid")
     held = moe.init_moe(jax.random.PRNGKey(3), 32, 24, 16, n_held=4, scoring="sigmoid")

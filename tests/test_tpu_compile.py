@@ -16,6 +16,7 @@ process may load the TPU library, and every test worker imports this file.
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -145,9 +146,11 @@ def test_moonlight_cell_train_step_compiles_for_v5e(topo, monkeypatch):
     dense layer and 4 MoE layers, 8 of 64 experts held, 20,480 vocabulary
     rows), 4 x 8192 tokens, AdamW, full remat. Latent attention takes the
     splash kernels at query/key width 192 and value width 128, the held
-    experts megablox's grouped matmuls; the step fits the chip's 15.75 GB
-    (5.69 GB of arguments and 9.62 GB of temporaries; a fifth MoE layer
-    does not fit)."""
+    experts megablox's grouped matmuls, their rows moved by the row
+    kernels (no XLA gather takes or gives all N·k = 196,608 assignment
+    rows); the step fits the chip's 15.75 GB (5.69 GB of arguments and
+    9.30 GB of temporaries, under the 9.62 GB of the gathers it replaced;
+    a fifth MoE layer does not fit)."""
     from repro.distributed.steps import make_train_step
     from repro.optim.adamw import adamw, cosine_schedule
     monkeypatch.setattr(ops, "_interpret", lambda: False)        # the CPU's branches
@@ -167,6 +170,20 @@ def test_moonlight_cell_train_step_compiles_for_v5e(topo, monkeypatch):
     text = compiled.as_text()
     assert "splash_mqa_fwd_residuals" in text and "splash_mqa_dkv" in text
     assert "%gmm" in text and "%tgmm" in text
+    assert "%gather_rows" in text and "%combine_rows" in text
+    assert _gathers_of_rows(text, 4 * 8192 * 6, 2048) == []
     mem = compiled.memory_analysis()
     assert 5.6e9 < mem.argument_size_in_bytes < 5.8e9      # 10 B a parameter, 569M
+    assert mem.temp_size_in_bytes <= 9.621e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def _gathers_of_rows(text, rows, width):
+    """XLA gathers in compiled HLO text whose operand or result is (rows,
+    width): (name, shape) pairs. Instruction names are unique in a module,
+    fused computations' parameters included."""
+    shapes = dict(re.findall(r"(%[\w.\-]+) = (\w+\[[\d,]*\])", text))
+    found = re.findall(r"(%[\w.\-]+) = (\w+\[[\d,]*\])\S* gather\((%[\w.\-]+)", text)
+    return [(name, shape) for name, result, operand in found
+            for shape in (result, shapes.get(operand, ""))
+            if shape.endswith(f"[{rows},{width}]")]
