@@ -1,4 +1,4 @@
-"""Benchmark driver — one module per paper table/figure (+ the roofline).
+"""Benchmark driver — one module per paper table/figure.
 
 Prints a ``name,us_per_call,derived`` CSV summary at the end (harness
 contract); each module also prints its human-readable table.
@@ -7,7 +7,6 @@ contract); each module also prints its human-readable table.
   scaling_single_node   — paper Figs. 6 (weak) & 7 (strong)
   scaling_multi_node    — paper Figs. 8 (weak) & 9 (strong)
   trace_analysis        — paper Fig. 10
-  roofline              — §Roofline from the dry-run artifacts
 """
 from __future__ import annotations
 
@@ -19,18 +18,17 @@ import traceback
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
-                    help="comma list: serialization,scaling1,scalingN,trace,roofline")
+                    help="comma list: serialization,scaling1,scalingN,trace")
     args = ap.parse_args()
     want = set(args.only.split(",")) if args.only else None
 
-    from . import (roofline, scaling_multi_node, scaling_single_node,
+    from . import (scaling_multi_node, scaling_single_node,
                    serialization_bench, trace_analysis)
     benches = [
         ("serialization", serialization_bench.run),
         ("scaling1", scaling_single_node.run),
         ("scalingN", scaling_multi_node.run),
         ("trace", trace_analysis.run),
-        ("roofline", roofline.run),
     ]
     rows = []
     failed = False

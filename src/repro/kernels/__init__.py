@@ -5,8 +5,6 @@ pure-jnp oracle in ``ref.py`` and a jit'd wrapper in ``ops.py``:
   splash kernels (fused forward, fused dq and dk/dv backward); the one
   kernel on the model's path, selected by ``layers.attention.attn_path``
   for training and prefill on a single TPU
-* ``flash_attention`` — the repo's own forward-only GQA causal/windowed
-  flash kernel; reached only by its tests
 * ``moe_rows``        — the routed experts' row gather and per-token
   weighted sum, over the rows below a count known at run time
 * ``ssd_scan``        — Mamba-2 SSD chunked scan
@@ -19,7 +17,6 @@ Validated in interpret mode on CPU; TPU is the target (BlockSpec VMEM
 tiling, MXU-shaped dot_generals, accumulate-in-output grid patterns).
 """
 from . import ops, ref  # noqa: F401
-from .flash_attention import flash_attention  # noqa: F401
 from .kmeans_assign import kmeans_assign  # noqa: F401
 from .knn_topk import knn_topk  # noqa: F401
 from .rglru_scan import rglru_scan  # noqa: F401

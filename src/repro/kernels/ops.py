@@ -10,8 +10,7 @@ model-layer calling conventions.
 splash-attention kernels: one fused kernel forward and one backward (dq,
 dk and dv together), so scores and probabilities never reach HBM.
 ``layers.attention.attn_forward`` selects it for training and prefill on a
-single TPU (``attn_path``); the repo's own forward-only
-``flash_attention`` kernel is reached only by its tests.
+single TPU (``attn_path``).
 
 ``grouped_matmul`` multiplies row groups by their own matrices through
 JAX's Pallas megablox kernels: the routed experts of

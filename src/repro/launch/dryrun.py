@@ -122,18 +122,11 @@ def probe_roofline(cfg, shape, mesh, rules, mb_real: int) -> dict:
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
              rules_override: dict | None = None, microbatches: int | None = None,
-             tag: str = "", probes: bool = True,
-             cfg_overrides: dict | None = None) -> dict:
+             tag: str = "", probes: bool = True) -> dict:
     cfg = get_config(arch)
-    if cfg_overrides:
-        cfg = dataclasses.replace(cfg, **cfg_overrides)
-        cell_overrides = dict(cfg_overrides)
-    else:
-        cell_overrides = {}
     shape = SHAPES[shape_name]
     mesh_name = "2x16x16" if multi_pod else "16x16"
-    cell = {"arch": arch, "shape": shape_name, "mesh": mesh_name, "tag": tag,
-            "cfg_overrides": cell_overrides}
+    cell = {"arch": arch, "shape": shape_name, "mesh": mesh_name, "tag": tag}
     if not shape_applicable(cfg, shape_name):
         cell["status"] = "SKIP"
         cell["reason"] = ("long_500k requires sub-quadratic attention; "
@@ -213,22 +206,7 @@ def main() -> None:
     ap.add_argument("--rules", default=None,
                     help="comma list of logical=mesh overrides, e.g. "
                          "'embed=model,mlp=data'")
-    ap.add_argument("--set", dest="cfg_set", default=None,
-                    help="comma list of LMConfig overrides, e.g. "
-                         "'tp_block=shard_map,attn_scores_bf16=1'")
     args = ap.parse_args()
-
-    cfg_overrides = None
-    if args.cfg_set:
-        cfg_overrides = {}
-        for kv in args.cfg_set.split(","):
-            k, _, v = kv.partition("=")
-            if v in ("0", "1"):
-                cfg_overrides[k] = bool(int(v))
-            elif v.isdigit():
-                cfg_overrides[k] = int(v)
-            else:
-                cfg_overrides[k] = v
 
     overrides = None
     if args.rules:
@@ -251,8 +229,7 @@ def main() -> None:
                 try:
                     cell = run_cell(arch, shape, mp, out, overrides,
                                     args.microbatches, args.tag,
-                                    probes=not args.no_probes,
-                                    cfg_overrides=cfg_overrides)
+                                    probes=not args.no_probes)
                     status = cell["status"]
                     extra = ""
                     if status == "OK":

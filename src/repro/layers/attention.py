@@ -125,13 +125,11 @@ def _dense_attn(q, k, v, q_pos, kv_pos, window, scale: Optional[float] = None):
 
 @jax.named_scope("scores")
 def _chunked_attn(q, k, v, q_pos, kv_pos, window, chunk: int = 1024,
-                  unroll: bool = False, scores_dtype=jnp.float32,
-                  scale: Optional[float] = None):
-    """Streaming (online-softmax) attention over KV chunks.
-
-    ``scores_dtype=bfloat16`` stores the (B,K,G,Sq,chunk) score/probability
-    tensors in bf16 (running max/denominator stay fp32) — the flash-kernel
-    convention; halves the dominant HBM traffic of the jnp twin."""
+                  unroll: bool = False, scale: Optional[float] = None):
+    """Streaming (online-softmax) attention over KV chunks: q: (B,Sq,K,G,hd);
+    k: (B,Skv,K,hd); v: (B,Skv,K,hd_v) -> (B,Sq,K,G,hd_v) fp32.  Scores,
+    probabilities, the running max and denominator are fp32; memory is
+    O(Sq·chunk) per head."""
     B, Sq, K, G, hd = q.shape
     Skv = k.shape[1]
     chunk = min(chunk, Skv)
@@ -146,22 +144,20 @@ def _chunked_attn(q, k, v, q_pos, kv_pos, window, chunk: int = 1024,
     vc = v.reshape(B, n_chunks, chunk, K, hd_v).transpose(1, 0, 2, 3, 4)
     pc = kv_pos.reshape(n_chunks, chunk)
     scale = 1.0 / math.sqrt(hd) if scale is None else scale
-    sd = scores_dtype
-    qf = q.astype(sd)
+    qf = q.astype(jnp.float32)
 
     def step(carry, inp):
         m, lse, acc = carry
         k_i, v_i, pos_i = inp
-        s = (jnp.einsum("bqkgh,bskh->bkgqs", qf, k_i.astype(sd)) * scale
-             ).astype(sd)
+        s = jnp.einsum("bqkgh,bskh->bkgqs", qf, k_i.astype(jnp.float32)) * scale
         msk = _mask(q_pos, pos_i, window)
-        s = jnp.where(msk[None, None, None], s, jnp.asarray(NEG_INF, sd))
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1).astype(jnp.float32))
+        s = jnp.where(msk[None, None, None], s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s.astype(jnp.float32) - m_new[..., None]).astype(sd)
-        l_new = lse * alpha + jnp.sum(p.astype(jnp.float32), axis=-1)
+        p = jnp.exp(s - m_new[..., None])
+        l_new = lse * alpha + jnp.sum(p, axis=-1)
         acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bkgqs,bskh->bkgqh", p, v_i.astype(sd)).astype(jnp.float32)
+            "bkgqs,bskh->bkgqh", p, v_i.astype(jnp.float32))
         return (m_new, l_new, acc_new), None
 
     m0 = jnp.full((B, K, G, Sq), NEG_INF, dtype=jnp.float32)
@@ -222,7 +218,6 @@ def attn_forward(
     impl: str = "auto",
     chunk: int = 1024,
     unroll: bool = False,
-    scores_dtype=jnp.float32,
     mesh=None,
 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """Returns (output, new_cache).  ``mesh``: the mesh the step runs on,
@@ -263,7 +258,7 @@ def attn_forward(
 
     o = attend(q.reshape(B, S, K, G, head_dim), k_all, v_all, positions, kv_pos,
                window=window, cached=cache is not None, impl=impl, chunk=chunk,
-               unroll=unroll, scores_dtype=scores_dtype, mesh=mesh)
+               unroll=unroll, mesh=mesh)
     o = o.astype(x.dtype).reshape(B, S, n_heads * head_dim)
     return o @ params["wo"], new_cache
 
@@ -271,7 +266,7 @@ def attn_forward(
 def attend(q, k, v, positions, kv_pos, *, window: Optional[int] = None,
            cached: bool = False, scale: Optional[float] = None,
            impl: str = "auto", chunk: int = 1024, unroll: bool = False,
-           scores_dtype=jnp.float32, mesh=None):
+           mesh=None):
     """Attention over projected heads, on the path :func:`attn_path` picks
     (counted as ``attn.impl``).  q: (B,S,K,G,hd); k: (B,Skv,K,hd); v:
     (B,Skv,K,hd_v) -> (B,S,K,G,hd_v).  ``scale``: 1/sqrt(hd) where not
@@ -285,5 +280,5 @@ def attend(q, k, v, positions, kv_pos, *, window: Optional[int] = None,
         return _flash_attn(q, k, v, scale=scale)
     if path == "chunked":
         return _chunked_attn(q, k, v, positions, kv_pos, window, chunk=chunk,
-                             unroll=unroll, scores_dtype=scores_dtype, scale=scale)
+                             unroll=unroll, scale=scale)
     return _dense_attn(q, k, v, positions, kv_pos, window, scale=scale)

@@ -65,7 +65,7 @@ def mla_axes():
 def mla_forward(params, x, *, n_heads: int, kv_lora_rank: int, qk_nope_dim: int,
                 qk_rope_dim: int, v_head_dim: int, rope_theta: float,
                 norm_eps: float, impl: str = "auto", chunk: int = 1024,
-                unroll: bool = False, scores_dtype=jnp.float32, mesh=None):
+                unroll: bool = False, mesh=None):
     """x: (B, S, D) -> (B, S, D); causal self-attention over the block."""
     B, S, _ = x.shape
     H, nope, rope = n_heads, qk_nope_dim, qk_rope_dim
@@ -82,5 +82,5 @@ def mla_forward(params, x, *, n_heads: int, kv_lora_rank: int, qk_nope_dim: int,
                         axis=-1)
     o = attend(q[:, :, :, None], k, kv[..., nope:], positions, positions,
                scale=1.0 / math.sqrt(nope + rope), impl=impl, chunk=chunk,
-               unroll=unroll, scores_dtype=scores_dtype, mesh=mesh)
+               unroll=unroll, mesh=mesh)
     return o.astype(x.dtype).reshape(B, S, H * v_head_dim) @ params["wo"]

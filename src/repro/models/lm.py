@@ -31,7 +31,6 @@ from ..layers.mlp import init_mlp, mlp_axes, mlp_forward
 from ..layers.mla import init_mla, mla_axes, mla_forward
 from ..layers.moe import init_moe, moe_apply_local, moe_apply_sharded, moe_axes
 from ..layers.norms import init_rmsnorm, rmsnorm, rmsnorm_axes
-from ..layers.tp_block import tp_attn_sublayer, tp_mlp_sublayer, tp_rglru_sublayer
 from ..layers.rglru import init_rglru, init_rglru_cache, rglru_axes, rglru_forward
 from ..layers.ssd import init_ssd, init_ssd_cache, ssd_axes, ssd_forward
 
@@ -95,10 +94,6 @@ class LMConfig:
     unroll_scans: bool = False
     # distribution hints (consumed by repro.distributed)
     moe_ff_shard_axis: Optional[str] = "data"
-    # §Perf levers: explicit shard_map TP for dense sub-blocks (train path)
-    # and bf16 storage for attention score/probability tensors
-    tp_block: str = "gspmd"          # "gspmd" | "shard_map"
-    attn_scores_bf16: bool = False
 
     @property
     def hd(self) -> int:
@@ -306,30 +301,11 @@ def _apply_block(cfg: LMConfig, p, x, btype: str, *, cache, pos_offset,
     rows = None
     eps = cfg.norm_eps
     if btype in ("dense", "local_attn", "moe"):
-        scores_dtype = jnp.bfloat16 if cfg.attn_scores_bf16 else jnp.float32
         window = cfg.local_window if btype == "local_attn" else None
         mcl = make_cache_len
         if btype == "local_attn" and mcl is not None:
             mcl = min(mcl, cfg.local_window)
-        tp = mesh.shape.get("model", 1) if mesh is not None else 1
-        use_tp = (cfg.tp_block == "shard_map" and tp > 1
-                  and cache is None and mcl is None and not cfg.kv_lora_rank
-                  and cfg.n_heads % tp == 0)  # heads must divide the TP axis
-        if use_tp:
-            data_axes = tuple(a for a in ("pod", "data")
-                              if a in mesh.axis_names)
-            x = tp_attn_sublayer(p["ln1"], p["attn"], x, cfg=cfg, mesh=mesh,
-                                 window=window, pos_offset=pos_offset,
-                                 data_axes=data_axes)
-            new_cache = None
-            if btype != "moe" and cfg.d_ff % tp == 0:
-                x = tp_mlp_sublayer(p["ln2"], p["mlp"], x, cfg=cfg, mesh=mesh,
-                                    data_axes=data_axes)
-                return x, None, aux, rows
-            if btype != "moe":
-                x = x + mlp_forward(p["mlp"], rmsnorm(p["ln2"], x, eps))
-                return x, None, aux, rows
-        elif cfg.kv_lora_rank:
+        if cfg.kv_lora_rank:
             if cache is not None or mcl is not None:
                 raise NotImplementedError("latent attention keeps no KV cache")
             x = x + mla_forward(
@@ -337,8 +313,7 @@ def _apply_block(cfg: LMConfig, p, x, btype: str, *, cache, pos_offset,
                 kv_lora_rank=cfg.kv_lora_rank, qk_nope_dim=cfg.qk_nope_dim,
                 qk_rope_dim=cfg.qk_rope_dim, v_head_dim=cfg.v_head_dim,
                 rope_theta=cfg.rope_theta, norm_eps=eps, impl=cfg.attn_impl,
-                chunk=cfg.attn_chunk, unroll=cfg.unroll_scans,
-                scores_dtype=scores_dtype, mesh=mesh)
+                chunk=cfg.attn_chunk, unroll=cfg.unroll_scans, mesh=mesh)
             new_cache = None
         else:
             a, new_cache = attn_forward(
@@ -347,8 +322,7 @@ def _apply_block(cfg: LMConfig, p, x, btype: str, *, cache, pos_offset,
                 rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm, window=window,
                 pos_offset=pos_offset, cache=cache, make_cache_len=mcl,
                 cache_dtype=cfg.cache_dtype, impl=cfg.attn_impl,
-                chunk=cfg.attn_chunk, unroll=cfg.unroll_scans,
-                scores_dtype=scores_dtype, mesh=mesh)
+                chunk=cfg.attn_chunk, unroll=cfg.unroll_scans, mesh=mesh)
             x = x + a
         h = rmsnorm(p["ln2"], x, eps)
         if btype == "moe":
@@ -378,19 +352,6 @@ def _apply_block(cfg: LMConfig, p, x, btype: str, *, cache, pos_offset,
             make_cache=make_cache_len is not None, unroll=cfg.unroll_scans)
         return x + y, new_cache, aux, rows
     if btype == "rglru":
-        tp = mesh.shape.get("model", 1) if mesh is not None else 1
-        use_tp = (cfg.tp_block == "shard_map" and tp > 1 and cache is None
-                  and make_cache_len is None
-                  and (cfg.rnn_width or cfg.d_model) % tp == 0
-                  and cfg.d_ff % tp == 0)
-        if use_tp:
-            data_axes = tuple(a for a in ("pod", "data")
-                              if a in mesh.axis_names)
-            x = tp_rglru_sublayer(p["ln1"], p["rec"], x, cfg=cfg, mesh=mesh,
-                                  data_axes=data_axes)
-            x = tp_mlp_sublayer(p["ln2"], p["mlp"], x, cfg=cfg, mesh=mesh,
-                                data_axes=data_axes)
-            return x, None, aux, rows
         y, new_cache = rglru_forward(p["rec"], rmsnorm(p["ln1"], x, eps), cache=cache,
                                      make_cache=make_cache_len is not None)
         x = x + y

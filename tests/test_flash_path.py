@@ -1,6 +1,7 @@
 """The fused attention path: the splash kernels (interpret mode on the CPU)
 against the dense jnp path, forward and gradients, at tiny GQA shapes; the
-rule that selects the path; and the ``attn.impl`` counter."""
+chunked jnp path against the float32 oracle; the rule that selects the
+path; and the ``attn.impl`` counter."""
 import dataclasses
 import functools
 
@@ -11,9 +12,10 @@ import pytest
 
 from repro.core import tracing
 from repro.core.tracing import TaskStream
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.layers import attention
-from repro.layers.attention import _dense_attn, _flash_attn, attn_forward, attn_path
+from repro.layers.attention import (_chunked_attn, _dense_attn, _flash_attn, attn_forward,
+                                    attn_path)
 from repro.models.lm import LMConfig, init_params, loss_fn
 
 B, K, G, S, HD = 1, 2, 2, 256, 128
@@ -102,6 +104,25 @@ def test_fused_path_is_causal(s, k_heads):
                                   np.asarray(o2[:, :-1], np.float32))
     assert not np.array_equal(np.asarray(o1[:, -1], np.float32),
                               np.asarray(o2[:, -1], np.float32))
+
+
+@pytest.mark.parametrize("window", [None, 7])
+def test_chunked_attn_matches_reference(window):
+    """The streaming-softmax jnp path over three KV chunks, causal and
+    sliding-window, against ``kernels.ref.flash_attention_ref``."""
+    b, k_heads, g, s, d = 1, 2, 2, 48, 16
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (b, s, k_heads, g, d))
+    k = jax.random.normal(ks[1], (b, s, k_heads, d))
+    v = jax.random.normal(ks[2], (b, s, k_heads, d))
+    pos = jnp.arange(s, dtype=jnp.int32)
+    o = jax.jit(_chunked_attn, static_argnames=("window", "chunk"))(
+        q, k, v, pos, pos, window=window, chunk=16)
+    r = ref.flash_attention_ref(
+        q.transpose(0, 2, 3, 1, 4).reshape(b, k_heads * g, s, d),
+        k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), window=window)
+    r = r.reshape(b, k_heads, g, s, d).transpose(0, 3, 1, 2, 4)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=3e-5)
 
 
 # the benchmark's training shape on one chip

@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention
 from repro.kernels.kmeans_assign import kmeans_assign
 from repro.kernels.knn_topk import knn_topk
 from repro.kernels.rglru_scan import rglru_scan
@@ -19,36 +18,6 @@ pytestmark = pytest.mark.slow
 
 def _tol(dtype):
     return 2e-2 if dtype == jnp.bfloat16 else 3e-5
-
-
-@pytest.mark.parametrize("B,H,K,Sq,Skv,d", [
-    (2, 4, 2, 64, 64, 32),
-    (1, 4, 1, 100, 100, 16),   # MQA + ragged
-    (2, 8, 8, 128, 128, 64),   # MHA
-    (1, 4, 2, 80, 200, 32),    # cross-length
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_sweep(B, H, K, Sq, Skv, d, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (B, H, Sq, d), dtype)
-    k = jax.random.normal(ks[1], (B, K, Skv, d), dtype)
-    v = jax.random.normal(ks[2], (B, K, Skv, d), dtype)
-    o = flash_attention(q, k, v, block_q=32, block_k=32, interpret=True)
-    r = ref.flash_attention_ref(q, k, v)
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(r, np.float32), atol=_tol(dtype))
-
-
-@pytest.mark.parametrize("window", [1, 7, 64])
-def test_flash_attention_window(window):
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = jax.random.normal(ks[0], (1, 2, 65, 16))
-    k = jax.random.normal(ks[1], (1, 1, 65, 16))
-    v = jax.random.normal(ks[2], (1, 1, 65, 16))
-    o = flash_attention(q, k, v, window=window, block_q=32, block_k=32,
-                        interpret=True)
-    r = ref.flash_attention_ref(q, k, v, window=window)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=3e-5)
 
 
 @pytest.mark.parametrize("B,S,H,P,N,chunk", [
@@ -149,24 +118,3 @@ def test_flash_custom_vjp_grads_match_reference():
         g2 = jax.grad(lambda *a: jnp.sum(via_ref(*a) * w),
                       argnums=argnum)(q, k, v)
         assert rel(g1, g2) < 1e-2, argnum
-
-
-def test_kernel_matches_model_layer_attention():
-    """The kernel and the model's chunked-jnp twin agree (same math)."""
-    from repro.layers.attention import _chunked_attn
-    B, H, K, S, d = 1, 4, 2, 48, 16
-    ks = jax.random.split(jax.random.PRNGKey(11), 3)
-    q = jax.random.normal(ks[0], (B, H, S, d))
-    k = jax.random.normal(ks[1], (B, K, S, d))
-    v = jax.random.normal(ks[2], (B, K, S, d))
-    o_kernel = flash_attention(q, k, v, block_q=16, block_k=16, interpret=True)
-    # model layout: (B,S,K,G,hd) / (B,S,K,hd)
-    G = H // K
-    qg = q.reshape(B, K, G, S, d).transpose(0, 3, 1, 2, 4)
-    kk = k.transpose(0, 2, 1, 3)
-    vv = v.transpose(0, 2, 1, 3)
-    pos = jnp.arange(S, dtype=jnp.int32)
-    o_model = _chunked_attn(qg, kk, vv, pos, pos, None, chunk=16)
-    o_model = o_model.transpose(0, 2, 3, 1, 4).reshape(B, H, S, d)
-    np.testing.assert_allclose(np.asarray(o_kernel), np.asarray(o_model),
-                               atol=3e-5)

@@ -1,10 +1,9 @@
-"""§Perf variant correctness: the optimization levers must not change the
-computed function beyond dtype tolerance."""
+"""Performance knobs must not change the computed function beyond float
+tolerance."""
 import dataclasses
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro.models.lm import LMConfig, init_params, loss_fn
@@ -28,64 +27,6 @@ def batch_for(cfg, B=2, S=8):
         "targets": jax.random.randint(k, (B, S), 0, cfg.vocab_size),
         "loss_mask": jnp.ones((B, S)),
     }
-
-
-@pytest.mark.parametrize("kw", [
-    {},                                           # GQA
-    {"n_kv_heads": 1},                            # MQA
-    {"n_kv_heads": 4},                            # MHA
-    {"qk_norm": True},
-])
-def test_bf16_scores_close_to_fp32(kw):
-    cfg32 = tiny(**kw)
-    cfg16 = dataclasses.replace(cfg32, attn_scores_bf16=True)
-    params = init_params(cfg32, jax.random.PRNGKey(1))
-    b = batch_for(cfg32)
-    l32, _ = loss_fn(cfg32, params, b)
-    l16, _ = loss_fn(cfg16, params, b)
-    assert float(l32) == pytest.approx(float(l16), abs=3e-2)
-    g32 = jax.grad(lambda p: loss_fn(cfg32, p, b)[0])(params)
-    g16 = jax.grad(lambda p: loss_fn(cfg16, p, b)[0])(params)
-    for a, c in zip(jax.tree.leaves(g32), jax.tree.leaves(g16)):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(c, np.float32), atol=0.05)
-
-
-def test_tp_gqa_kv_gather_mapping():
-    """The replicated-KV TP branch gathers each local q head's *global*
-    kv-group: verify the index arithmetic over every (H, K, tp) layout the
-    assigned archs use."""
-    cases = [
-        (48, 1, 16),   # granite-20b MQA
-        (16, 8, 16),   # qwen3-0.6b (kv not divisible by tp -> replicated)
-        (32, 8, 16),   # granite-3-2b
-        (16, 1, 16),   # recurrentgemma
-        (64, 4, 16),   # qwen3-moe
-        (8, 2, 4),     # the reduced-config regression case
-    ]
-    for H, K, tp in cases:
-        if H % tp:
-            continue
-        G = H // K
-        H_l = H // tp
-        for d in range(tp):
-            gidx = (d * H_l + np.arange(H_l)) // G
-            expect = [(d * H_l + j) // G for j in range(H_l)]
-            np.testing.assert_array_equal(gidx, expect)
-            assert np.all(gidx < K)
-
-
-def test_tp_block_requires_divisible_heads():
-    """musicgen-style fallback: 24 heads on tp=16 must NOT take the TP path
-    (the config guard in models.lm); verified by the loss being identical
-    with and without the flag on a single device (where TP never engages)."""
-    cfg = tiny(n_heads=4, n_kv_heads=4)
-    cfg_tp = dataclasses.replace(cfg, tp_block="shard_map")
-    params = init_params(cfg, jax.random.PRNGKey(1))
-    b = batch_for(cfg)
-    l0, _ = loss_fn(cfg, params, b)
-    l1, _ = loss_fn(cfg_tp, params, b)  # mesh=None -> GSPMD path
-    assert float(l0) == float(l1)
 
 
 def test_ssm_chunk_is_a_pure_performance_knob():
