@@ -10,7 +10,9 @@ model-layer calling conventions.
 splash-attention kernels: one fused kernel forward and one backward (dq,
 dk and dv together), so scores and probabilities never reach HBM.
 ``layers.attention.attn_forward`` selects it for training and prefill on a
-single TPU (``attn_path``).
+single TPU (``attn_path``).  The forward names its output and log-sum-exp
+``ATTN_RESIDUALS``, which ``models.lm``'s rematerialisation keeps, so the
+backward does not run the forward kernel again.
 
 ``grouped_matmul`` multiplies row groups by their own matrices through
 JAX's Pallas megablox kernels: the routed experts of
@@ -58,6 +60,10 @@ def _interpret() -> bool:
 _FWD_BLOCKS = (512, 1024)
 _BWD_BLOCKS = (1024, 1024)
 _LANES = 128
+# the name of the forward's output and log-sum-exp for ``jax.checkpoint``
+# policies: the backward kernel's only residuals that the layer's own
+# inputs do not give again
+ATTN_RESIDUALS = "attn_residuals"
 
 
 def _fit(block: int, seq_len: int) -> int:
@@ -97,11 +103,14 @@ def flash_attention_op(q, k, v, scale: Optional[float] = None):
     (B, K, G, S, hd_v) bfloat16; the value width may differ from the
     query/key width (latent attention: 192 and 128).
     Each (batch, KV head) pair is one MQA problem for the splash kernels:
-    bfloat16 operands into the MXU with float32 accumulation, float32
-    softmax statistics, probabilities rounded to bfloat16 for the value
-    product; dq is summed over the backward's KV tiles from bfloat16
-    partials.  ``scale`` (1/sqrt(hd) where not given) is applied to q in
-    float32 before its one rounding to bfloat16."""
+    the scores a bfloat16 product with float32 accumulation, float32
+    softmax statistics, and the value product in float32 (the forward
+    widens each value tile to float32 and multiplies the float32
+    probabilities by it); the output is rounded to bfloat16 once, and dq
+    is summed over the backward's KV tiles from bfloat16 partials.  The
+    output and the log-sum-exp carry the checkpoint name
+    ``ATTN_RESIDUALS``.  ``scale`` (1/sqrt(hd) where not given) is applied
+    to q in float32 before its one rounding to bfloat16."""
     B, K, G, S, hd = q.shape
     blocks = flash_block_sizes(S, hd, v.shape[-1])
     if blocks is None or k.shape[2] != S:
@@ -109,7 +118,7 @@ def flash_attention_op(q, k, v, scale: Optional[float] = None):
                          f"v {v.shape}")
     kernel = make_splash_mqa_single_device(
         MultiHeadMask([CausalMask((S, S))] * G), block_sizes=blocks,
-        interpret=_interpret())
+        residual_checkpoint_name=ATTN_RESIDUALS, interpret=_interpret())
     scale = 1.0 / math.sqrt(hd) if scale is None else scale
     q = (q.astype(jnp.float32) * scale).astype(jnp.bfloat16)
     k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)

@@ -26,6 +26,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..core import tracing
+from ..kernels.ops import ATTN_RESIDUALS
 from ..layers.attention import attention_axes, attn_forward, init_attention, init_kv_cache
 from ..layers.mlp import init_mlp, mlp_axes, mlp_forward
 from ..layers.mla import init_mla, mla_axes, mla_forward
@@ -85,7 +87,9 @@ class LMConfig:
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.float32
     cache_dtype: Any = jnp.bfloat16
-    remat: str = "none"            # none | full | dots
+    # none | full (keeps each block's inputs and the fused attention's output
+    # and log-sum-exp) | dots (those and the matmul outputs)
+    remat: str = "none"
     attn_impl: str = "auto"
     attn_chunk: int = 1024
     # dry-run cost probes: python-loop the layers / unroll inner scans so
@@ -398,12 +402,19 @@ def embed_inputs(cfg: LMConfig, params, batch) -> jnp.ndarray:
 
 
 def _remat(fn, remat: str):
-    """``fn`` rematerialised as ``remat`` says: ``full`` keeps only its
-    inputs for the backward, ``dots`` its matmul outputs too."""
+    """``fn`` rematerialised as ``remat`` says: ``full`` keeps its inputs
+    and the fused attention's output and log-sum-exp (``ATTN_RESIDUALS``)
+    for the backward, ``dots`` its matmul outputs too.  The backward then
+    recomputes q, k and v but not the attention forward; on the jnp
+    attention paths nothing carries the name and ``full`` keeps only the
+    inputs."""
+    policies = jax.checkpoint_policies
+    keep = policies.save_only_these_names(ATTN_RESIDUALS)
     if remat == "full":
-        return jax.checkpoint(fn)
+        return jax.checkpoint(fn, policy=keep)
     if remat == "dots":
-        return jax.checkpoint(fn, policy=jax.checkpoint_policies.checkpoint_dots)
+        return jax.checkpoint(fn, policy=policies.save_from_both_policies(
+            policies.checkpoint_dots, keep))
     return fn
 
 
@@ -417,6 +428,8 @@ def forward(cfg: LMConfig, params, batch, *, caches=None, pos_offset=0,
     ``last_only=True`` computes logits for the final position only (prefill
     memory saver: avoids materializing (B, S, V))."""
     remat = cfg.remat if remat is None else remat
+    if remat != "none":
+        tracing.count("remat.policy", 1, mode=remat, keeps=ATTN_RESIDUALS)
     if cfg.first_k_dense and (caches is not None or make_cache_len is not None):
         raise NotImplementedError("the leading dense layers keep no KV cache")
     x = embed_inputs(cfg, params, batch)

@@ -1,7 +1,8 @@
 """The fused attention path: the splash kernels (interpret mode on the CPU)
 against the dense jnp path, forward and gradients, at tiny GQA shapes; the
 chunked jnp path against the float32 oracle; the rule that selects the
-path; and the ``attn.impl`` counter."""
+path; the ``attn.impl`` and ``remat.policy`` counters; and the kept forward
+residuals under rematerialisation."""
 import dataclasses
 import functools
 
@@ -224,6 +225,72 @@ def test_remat_train_step_flash_matches_dense(on_tpu, ring):
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_flash),
                             jax.tree.leaves(g_dense)):
         assert _rel(a, b) < 3e-2, jax.tree_util.keystr(path)
+
+
+def _splash_calls(jaxpr, calls=None):
+    """Names of the ``pallas_call`` equations in ``jaxpr`` and in every
+    jaxpr its equations carry (scan, checkpoint, custom_vjp, pjit)."""
+    calls = [] if calls is None else calls
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls.append(str(eqn.params["name"]))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    _splash_calls(sub.jaxpr, calls)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    _splash_calls(sub, calls)
+    return calls
+
+
+# (config changes, attention call sites): the scanned layers are one site;
+# latent attention adds a leading dense layer, run unrolled
+REMAT_MODELS = {
+    "gqa": ({}, 1),
+    "mla": (dict(n_layers=3, n_heads=2, n_kv_heads=2, first_k_dense=1,
+                 kv_lora_rank=64, qk_nope_dim=128, qk_rope_dim=64,
+                 v_head_dim=128), 2),
+}
+
+
+@pytest.mark.parametrize("model", sorted(REMAT_MODELS))
+@pytest.mark.parametrize("remat", ["full", "dots"])
+def test_remat_keeps_the_fused_forward_residuals(on_tpu, ring, remat, model):
+    """Rematerialised, the gradient's program runs the splash forward once
+    per attention call site (the backward takes the kept output and
+    log-sum-exp in place of a second forward), and gives the loss and
+    gradients of the program that keeps everything."""
+    change, sites = REMAT_MODELS[model]
+    cfg = LMConfig(**{**dict(name="remat-tiny", n_layers=2, d_model=256,
+                             n_heads=K * G, n_kv_heads=K, head_dim=HD, d_ff=512,
+                             vocab_size=128, param_dtype=jnp.bfloat16,
+                             compute_dtype=jnp.bfloat16, remat=remat), **change})
+    params = init_params(cfg, jax.random.PRNGKey(12))
+    kt = jax.random.split(jax.random.PRNGKey(13))
+    batch = {"tokens": jax.random.randint(kt[0], (B, S), 0, cfg.vocab_size),
+             "targets": jax.random.randint(kt[1], (B, S), 0, cfg.vocab_size),
+             "loss_mask": jnp.ones((B, S))}
+
+    def value_and_grad(mode):
+        return jax.jit(jax.value_and_grad(
+            lambda p: loss_fn(cfg, p, batch, remat=mode)[0]))
+
+    step = value_and_grad(remat)
+    calls = _splash_calls(jax.make_jaxpr(step)(params).jaxpr)
+    assert sum(c.startswith("splash_mqa_fwd") for c in calls) == sites, calls
+    assert sum(c.startswith("splash_mqa_dkv") for c in calls) == sites, calls
+    assert [(r["value"], r["args"]) for r in ring.since(0)
+            if r["name"] == "remat.policy"] == [
+        (1, {"mode": remat, "keeps": ops.ATTN_RESIDUALS})]
+
+    l_remat, g_remat = step(params)
+    l_none, g_none = value_and_grad("none")(params)
+    assert abs(float(l_remat) - float(l_none)) < 1e-3 * float(l_none)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_remat),
+                            jax.tree.leaves(g_none)):
+        assert _rel(a, b) < 3e-2, jax.tree_util.keystr(path)
+    assert {r["args"]["mode"] for r in ring.since(0)
+            if r["name"] == "remat.policy"} == {remat}     # none records nothing
 
 
 def test_flash_op_refuses_shapes_it_cannot_tile():

@@ -148,9 +148,12 @@ def test_moonlight_cell_train_step_compiles_for_v5e(topo, monkeypatch):
     splash kernels at query/key width 192 and value width 128, the held
     experts megablox's grouped matmuls, their rows moved by the row
     kernels (no XLA gather takes or gives all N·k = 196,608 assignment
-    rows); the step fits the chip's 15.75 GB (5.69 GB of arguments and
-    9.30 GB of temporaries, under the 9.62 GB of the gathers it replaced;
-    a fifth MoE layer does not fit)."""
+    rows). Full remat keeps the splash forward's output and log-sum-exp,
+    so the forward kernel runs once per attention layer: twice in the step,
+    the dense layer's and the scanned MoE body's, where recomputing it in
+    the backward made four. The step fits the chip's 15.75 GB (5.69 GB of
+    arguments and 9.81 GB of temporaries, 0.51 GB of them the kept
+    residuals; a fifth MoE layer does not fit)."""
     from repro.distributed.steps import make_train_step
     from repro.optim.adamw import adamw, cosine_schedule
     monkeypatch.setattr(ops, "_interpret", lambda: False)        # the CPU's branches
@@ -168,13 +171,14 @@ def test_moonlight_cell_train_step_compiles_for_v5e(topo, monkeypatch):
                        donate_argnums=donate).lower(
         pshapes, jax.eval_shape(opt.init, pshapes), batch).compile()
     text = compiled.as_text()
-    assert "splash_mqa_fwd_residuals" in text and "splash_mqa_dkv" in text
+    assert len(re.findall(r"%splash_mqa_fwd_residuals[\w.\-]* = ", text)) == 2
+    assert len(re.findall(r"%splash_mqa_dkv[\w.\-]* = ", text)) == 2
     assert "%gmm" in text and "%tgmm" in text
     assert "%gather_rows" in text and "%combine_rows" in text
     assert _gathers_of_rows(text, 4 * 8192 * 6, 2048) == []
     mem = compiled.memory_analysis()
     assert 5.6e9 < mem.argument_size_in_bytes < 5.8e9      # 10 B a parameter, 569M
-    assert mem.temp_size_in_bytes <= 9.621e9
+    assert mem.temp_size_in_bytes <= 9.806e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
